@@ -94,9 +94,14 @@ def run(scale: float = 1.0):
     rows = [("bench", "kernel", "schedule", "devices", "us_per_call")]
     N = max(32, int(128 * scale))
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    print("# dist: child processes on fake CPU devices "
+          "(JAX_PLATFORMS=cpu); these rows are CPU timings", flush=True)
     for n in (1, 4):
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+        # fake CPU devices, and off the chip: the parent process already
+        # holds it, so a child that wanted it would fail or hang
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.path.join(repo, "src")
         env["BD_N"] = str(N)
         out = subprocess.run([sys.executable, "-c", SNIPPET], env=env,

@@ -69,10 +69,15 @@ for mode, make in [
 def run(scale: float = 1.0):
     rows = [("bench", "mode", "devices", "us_per_call", "nnz")]
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    print("# strong_scaling: child processes on fake CPU devices "
+          "(JAX_PLATFORMS=cpu); these rows are CPU timings", flush=True)
     failures = []
     for n in (1, 2, 4, 8):
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+        # fake CPU devices, and off the chip: the parent process already
+        # holds it, so a child that wanted it would fail or hang
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.path.join(repo, "src")
         env["BSS_N"] = str(max(64, int(256 * scale)))
         out = subprocess.run([sys.executable, "-c", SNIPPET], env=env,

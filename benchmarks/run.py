@@ -4,7 +4,8 @@
   §7 TTMc -> bench_ttmc          Fig 10a        -> bench_tttc
   Fig 10c -> bench_index_order   Alg 1          -> bench_search
   Fig 9/10b -> bench_strong_scaling (1..8 fake devices x both shard_map
-               engines — XLA collective and stacked Pallas; subprocesses)
+               engines — XLA collective and stacked Pallas; subprocesses
+               pinned to the CPU, so this process alone may hold a chip)
   MoE-SpTTN integration          -> bench_moe_dispatch
   §5.2 + DESIGN.md §7            -> bench_dist (1-vs-N tuned plan replay)
 
@@ -50,6 +51,8 @@ def medians(results: dict) -> dict:
 
 def main() -> int:
     scale = float(os.environ.get("SCALE", "0.5"))
+    from repro.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
     from benchmarks import (bench_dist, bench_index_order,
                             bench_moe_dispatch, bench_mttkrp,
                             bench_outofcore, bench_search,

@@ -11,9 +11,11 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from repro import (CSFArrays, build_csf, dense_oracle, execute_plan,
-                   make_executor, mttkrp, plan, plan_peak_bytes,
-                   random_sparse)
+from repro import (CSFArrays, build_csf, dense_oracle, execute_plan, mttkrp,
+                   plan, plan_peak_bytes, random_sparse)
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # MTTKRP (paper Eq. 1): A(i,a) = sum_jk T(i,j,k) B(j,a) C(k,a)
 SCALE = float(os.environ.get("EX_SCALE", "1.0"))
@@ -34,7 +36,7 @@ rng = np.random.default_rng(0)
 factors = {"B": jnp.asarray(rng.standard_normal((J, R)).astype(np.float32)),
            "C": jnp.asarray(rng.standard_normal((K, R)).astype(np.float32))}
 arrays = CSFArrays.from_csf(csf)
-out = make_executor(spec, p.path, p.order)(arrays, factors)
+out = execute_plan(p, arrays, factors)
 oracle = dense_oracle(spec, csf, {k: np.asarray(v)
                                   for k, v in factors.items()})
 err = float(np.abs(np.asarray(out) - oracle).max())

@@ -9,8 +9,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro import (CSFArrays, build_csf, make_executor, plan,
+from repro import (CSFArrays, build_csf, plan, plan_executor,
                    random_sparse, tttp3)
+from repro.compile_cache import enable_compile_cache
 
 
 def main(steps: int = 300, rank: int = 12, lr: float = 0.05):
@@ -27,7 +28,7 @@ def main(steps: int = 300, rank: int = 12, lr: float = 0.05):
 
     spec = tttp3(I, J, K, rank)
     p = plan(spec, nnz_levels=csf.nnz_levels())
-    ex = make_executor(spec, p.path, p.order)
+    ex = plan_executor(p)
     import dataclasses
     ones_arrays = dataclasses.replace(arrays,
                                       values=jnp.ones_like(arrays.values))
@@ -61,4 +62,5 @@ def main(steps: int = 300, rank: int = 12, lr: float = 0.05):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

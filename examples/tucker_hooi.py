@@ -8,11 +8,11 @@ import argparse
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
-from repro import (CSFArrays, build_csf, make_executor, parse, plan,
+from repro import (build_csf, jit_bound, parse, plan, plan_executor,
                    random_sparse)
+from repro.compile_cache import enable_compile_cache
 
 
 def main(steps: int = 8, ranks=(8, 6, 4), autotune: bool = False,
@@ -39,16 +39,13 @@ def main(steps: int = 8, ranks=(8, 6, 4), autotune: bool = False,
             how = "cache" if p.stats.cache_hit else (
                 f"search ({p.stats.candidates_timed} timed)")
             print(f"mode {mode}: plan from {how}", flush=True)
-        ex = make_executor(spec, p.path, p.order)
-        arrays = CSFArrays.from_csf(csf_m)
-        execs.append(jax.jit(
-            lambda u1, u2, ex=ex, arrays=arrays: ex(
-                arrays, {"U1": u1, "U2": u2})))
+        execs.append(jit_bound(plan_executor(p), csf_m))
 
     for it in range(steps):
         for mode in range(3):
             others = [m for m in range(3) if m != mode]
-            Y = execs[mode](U[others[0]], U[others[1]])   # (I_m, r1, r2)
+            Y = execs[mode]({"U1": U[others[0]],
+                             "U2": U[others[1]]})           # (I_m, r1, r2)
             Ym = np.asarray(Y).reshape(Y.shape[0], -1)
             u, s, _ = np.linalg.svd(Ym, full_matrices=False)
             U[mode] = jnp.asarray(u[:, : ranks[mode]])
@@ -65,5 +62,6 @@ if __name__ == "__main__":
     ap.add_argument("--cache-dir", default=None,
                     help="persist tuned plans here (skips re-search)")
     args = ap.parse_args()
+    enable_compile_cache()
     main(steps=args.steps, autotune=args.autotune,
          cache_dir=args.cache_dir)

@@ -44,6 +44,9 @@ _EXPORTS = {
     # execution (repro.core.executor)
     "make_executor": "repro.core.executor",
     "execute_plan": "repro.core.executor",
+    "plan_executor": "repro.core.executor",
+    "prepare_operand": "repro.core.executor",
+    "jit_bound": "repro.core.executor",
     "CSFArrays": "repro.core.executor",
     "dense_oracle": "repro.core.executor",
     "plan_to_json": "repro.core.executor",

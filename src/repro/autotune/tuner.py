@@ -114,6 +114,7 @@ class SearchStats:
     candidates_timed: int = 0
     executions: int = 0
     pruned: int = 0
+    infeasible: int = 0           # did not fit the device's memory
     vetoed: int = 0               # rejected by verify_plan pre-measurement
                                   # (E-severity diagnostics; DESIGN.md §11)
     search_seconds: float = 0.0
@@ -268,7 +269,12 @@ def tune(spec: SpTTNSpec,
     # win (measure_candidates sorts them last, but the skip is the
     # guarantee, not the sort).  All-pruned can only happen with a
     # degenerate prune_ratio; fall back to the least-bad sample then.
-    best = next((m for m in results if not m.pruned), results[0])
+    feasible = [m for m in results if not m.infeasible]
+    if not feasible:
+        raise ValueError(
+            "no candidate fits the device's memory: every measured "
+            "program failed with RESOURCE_EXHAUSTED")
+    best = next((m for m in feasible if not m.pruned), feasible[0])
     stats.best_seconds = best.seconds
     model_key = model_cand.key
     for m in results:
@@ -299,6 +305,7 @@ def tune(spec: SpTTNSpec,
             "mesh": None if config.mesh is None else dict(config.mesh),
             "timings": [
                 {"seconds": m.seconds, "pruned": m.pruned,
+                 "infeasible": m.infeasible,
                  "cost": m.candidate.cost, "flops": m.candidate.flops,
                  "backend": m.candidate.backend,
                  "fused": m.candidate.fused,

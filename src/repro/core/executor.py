@@ -341,9 +341,17 @@ class DenseVal:
     indices: tuple[str, ...]
 
 
+@jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class CSFArrays:
-    """Device-resident CSF (one-time upload; pattern is fixed)."""
+    """Device-resident CSF (one-time upload; pattern is fixed).
+
+    A pytree: its arrays, and the block layouts a Pallas engine attached
+    (:func:`prepare_operand`), are the leaves, and the fiber counts are
+    static.  So ``jax.jit(ex.__call__)(arrays, factors)`` compiles the
+    operand as arguments, one program per pattern shape, instead of
+    folding hundreds of MB of index arrays into the program as
+    constants.  The host tensor does not cross the boundary."""
     values: jnp.ndarray
     fiber_coord: dict[int, dict[int, jnp.ndarray]]  # level -> mode -> coords
     seg: dict[tuple[int, int], jnp.ndarray]         # (child, parent) -> map
@@ -367,6 +375,65 @@ class CSFArrays:
                    fiber_coord=fiber_coord, seg=seg,
                    nfib=dict(csf.nfib), order=csf.order,
                    shape=csf.shape, host=csf)
+
+    def tree_flatten(self):
+        layouts = self.__dict__.get("_codegen_layouts", {})
+        keys = tuple(layouts)
+        children = (self.values, self.fiber_coord, self.seg,
+                    tuple(layouts[k][1:] for k in keys))
+        aux = (tuple(sorted(self.nfib.items())), self.order,
+               tuple(self.shape), keys,
+               tuple(layouts[k][0] for k in keys))
+        return children, aux
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        nfib, order, shape, keys, nsegs = aux
+        values, fiber_coord, seg, entries = children
+        out = cls(values=values, fiber_coord=fiber_coord, seg=seg,
+                  nfib=dict(nfib), order=order, shape=shape)
+        if keys:
+            out.__dict__["_codegen_layouts"] = {
+                k: (n, *e) for k, n, e in zip(keys, nsegs, entries)}
+        return out
+
+
+def prepare_operand(ex, csf, factors: Mapping) -> CSFArrays:
+    """The operand, ready to be a ``jax.jit`` argument of ``ex``.
+
+    Pallas engines read their block layouts off the concrete segment
+    maps while tracing.  One abstract trace (``jax.eval_shape``, nothing
+    compiles) attaches every layout ``ex`` needs to the operand, on the
+    device, so a jitted ``ex.__call__(arrays, factors)`` receives them as
+    arguments like the rest of the CSF.  ``factors`` gives the shapes.
+    """
+    arrays = csf if isinstance(csf, CSFArrays) else CSFArrays.from_csf(csf)
+    jax.eval_shape(lambda f: ex(arrays, f), factors)
+    cache = arrays.__dict__.get("_codegen_layouts", {})
+    for k, (nseg, *entry) in cache.items():
+        cache[k] = (nseg, *jax.tree.map(jnp.asarray, entry))
+    return arrays
+
+
+def jit_bound(ex, csf, device=None):
+    """``factors -> ex(csf, factors)``, jitted, with the operand passed
+    as an argument (:func:`prepare_operand`, on the first call, when the
+    factor shapes are known).  With ``device`` the operand, the factors
+    and so the whole program live on that device."""
+    run = jax.jit(ex.__call__)
+    operand = None
+
+    def call(factors):
+        nonlocal operand
+        if operand is None:
+            operand = prepare_operand(ex, csf, factors)
+            if device is not None:
+                operand = jax.device_put(operand, device)
+        if device is not None:
+            factors = jax.device_put(dict(factors), device)
+        return run(operand, factors)
+
+    return call
 
 
 class VectorizedExecutor:
@@ -851,6 +918,14 @@ def execute_plan(plan, csf, factors: Mapping, backend: str | None = None,
     if getattr(plan, "slice_chunks", 1) > 1:
         from repro.core.slicing import sliced_execute
         return sliced_execute(plan, csf, factors, backend=backend, **kwargs)
+    return plan_executor(plan, backend=backend, **kwargs)(csf, factors)
+
+
+def plan_executor(plan, backend: str | None = None, **kwargs):
+    """The engine :func:`execute_plan` runs for ``plan``: its tuned
+    backend (unless overridden), and on a Pallas backend its tuned chain
+    fusion and block size.  For callers that jit the engine themselves
+    (``jax.jit(ex.__call__)`` over :func:`prepare_operand`'s operand)."""
     resolved = backend or plan.backend
     if resolved in PALLAS_BACKENDS and getattr(plan, "fused", False):
         # a fused-winner plan replays through the chain lowering it was
@@ -860,6 +935,5 @@ def execute_plan(plan, csf, factors: Mapping, backend: str | None = None,
     if resolved in PALLAS_BACKENDS and getattr(plan, "block", None):
         # ... and with the exact fiber block size that won (DESIGN.md §8)
         kwargs.setdefault("block", plan.block)
-    ex = make_executor(plan.spec, plan.path, plan.order,
-                       backend=resolved, **kwargs)
-    return ex(csf, factors)
+    return make_executor(plan.spec, plan.path, plan.order,
+                         backend=resolved, **kwargs)

@@ -18,18 +18,10 @@ import jax.numpy as jnp
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-    The two flags gate the same replication/varying-manual-axes check.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with the repo's keyword spelling (one call site
+    convention for the distributed engines and the tests)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def _quantize_block(x, key, block: int = 256):
@@ -71,10 +63,7 @@ def compressed_psum(x, axis_name: str, key, block: int = 256):
 def reduce_scatter_grads(grads, axis_name: str, tiled_axis: int = 0):
     """psum_scatter every leaf along ``axis_name`` (ZeRO-2 gradient shape).
     Leaves whose dim 0 does not divide the axis size are psum'd whole."""
-    if hasattr(jax.lax, "axis_size"):
-        size = jax.lax.axis_size(axis_name)
-    else:  # older jax: psum of a unit constant folds to the axis size
-        size = int(jax.lax.psum(1, axis_name))
+    size = jax.lax.axis_size(axis_name)
 
     def one(g):
         if g.ndim and g.shape[0] % size == 0 and g.shape[0] >= size:

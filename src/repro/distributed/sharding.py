@@ -18,9 +18,18 @@ import threading
 from collections.abc import Mapping, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _ctx = threading.local()
+
+
+def model_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """A mesh for the models: every axis ``Auto``.  The models place
+    activations with ``with_sharding_constraint`` and let GSPMD propagate
+    the rest, which JAX allows only on Auto axes (``jax.make_mesh``
+    makes Explicit axes unless told otherwise)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 # --------------------------------------------------------------------------- #

@@ -48,11 +48,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis.diagnostics import PALLAS_BACKENDS
 from repro.analysis.invariants import plan_layout_walk as _plan_layout_walk
-from repro.core.executor import CSFArrays, VectorizedExecutor
+from repro.core.executor import CSFArrays, VectorizedExecutor, jit_bound
 from repro.core.planner import SpTTNPlan
 from repro.core.spec import SpTTNSpec
 from repro.sparse.coo import COOTensor
@@ -298,6 +298,10 @@ def _compile_shard_map(mesh: Mesh, part: MeshPartition, local_fn,
     if extra_stacked:
         stacked.update(extra_stacked)
     csf_specs = {k: P(part.part_axes) for k in stacked}
+    # each shard's slice lives on its own device once, instead of being
+    # scattered from the default device on every call
+    stacked = {k: jax.device_put(v, NamedSharding(mesh, csf_specs[k]))
+               for k, v in stacked.items()}
     from repro.distributed.collectives import shard_map
     fn = jax.jit(shard_map(
         local_fn, mesh=mesh,
@@ -401,8 +405,6 @@ def _stacked_layout_tables(part: MeshPartition, ex, requests):
             extra[f"{name}__gather"] = np.stack([l.gather for l in lays])
             extra[f"{name}__mask"] = np.stack([l.mask for l in lays])
             extra[f"{name}__bseg"] = np.stack([l.block_seg for l in lays])
-            extra[f"{name}__bfirst"] = np.stack([l.block_first
-                                                 for l in lays])
             manifest.append(("stage", stage_layout_key(lvl, out_lvl,
                                                        ex.block),
                              name, nseg, 0))
@@ -415,27 +417,20 @@ def _stacked_layout_tables(part: MeshPartition, ex, requests):
             name = "chain_" + "_".join(map(str, (lvl0,) + levels))
             gathers, masks = [], []
             segs_j = [[] for _ in levels]
-            firsts_j = [[] for _ in levels]
-            lasts_j = [[] for _ in levels]
-            for lay, segs, firsts, lasts in per:
+            for lay, segs in per:
                 lay = pad_segment_layout(lay, pmax)
                 gathers.append(lay.gather)
                 masks.append(lay.mask)
                 for j in range(len(levels)):
                     nb = nbmax - segs[j].shape[0]
                     # inert appended blocks: edge segment id (contiguous
-                    # revisit of the final row), never first, never last
-                    # (no buffer reset, no flush)
+                    # revisit of the final row: no buffer reset, and the
+                    # final flush moves to the grid's end)
                     segs_j[j].append(np.pad(segs[j], (0, nb), mode="edge"))
-                    firsts_j[j].append(np.pad(firsts[j], (0, nb)))
-                    lasts_j[j].append(np.pad(lasts[j], (0, nb)))
             extra[f"{name}__gather"] = np.stack(gathers)
             extra[f"{name}__mask"] = np.stack(masks)
             for j in range(len(levels)):
                 extra[f"{name}__seg{j}"] = np.stack(segs_j[j])
-                extra[f"{name}__first{j}"] = np.stack(firsts_j[j])
-                if j < len(levels) - 1:   # outermost flush is the grid end
-                    extra[f"{name}__last{j}"] = np.stack(lasts_j[j])
             manifest.append(("chain", chain_layout_key(lvl0, levels,
                                                        ex.block),
                              name, part.max_nfib[levels[0]], len(levels)))
@@ -443,32 +438,23 @@ def _stacked_layout_tables(part: MeshPartition, ex, requests):
 
 
 def _install_stacked_layouts(arrays: CSFArrays, local: Mapping,
-                             manifest, block: int) -> None:
+                             manifest) -> None:
     """Populate the executor's layout cache with this shard's traced
-    slices so the Pallas lowering never touches numpy at trace time.
-    The ``lay`` slot becomes a static stub carrying only ``nseg`` —
-    the one attribute the lowering reads from it."""
+    slices so the Pallas lowering never touches numpy at trace time."""
     from repro.kernels.codegen.executor import (chain_cache_entry,
                                                 layout_cache,
                                                 stage_cache_entry)
-    from repro.kernels.util import PaddedSegments
 
     cache = layout_cache(arrays)
-    empty_i = np.zeros(0, np.int32)
     for kind, key, name, nseg, nlvl in manifest:
-        stub = PaddedSegments(gather=empty_i, mask=np.zeros(0, np.float32),
-                              block_seg=empty_i, block_first=empty_i,
-                              nseg=nseg, block=block)
         if kind == "stage":
             cache[key] = stage_cache_entry(
-                stub, local[f"{name}__gather"], local[f"{name}__mask"],
-                local[f"{name}__bseg"], local[f"{name}__bfirst"])
+                nseg, local[f"{name}__gather"], local[f"{name}__mask"],
+                local[f"{name}__bseg"])
         else:
             cache[key] = chain_cache_entry(
-                stub, local[f"{name}__gather"], local[f"{name}__mask"],
-                tuple(local[f"{name}__seg{j}"] for j in range(nlvl)),
-                tuple(local[f"{name}__first{j}"] for j in range(nlvl)),
-                tuple(local[f"{name}__last{j}"] for j in range(nlvl - 1)))
+                nseg, local[f"{name}__gather"], local[f"{name}__mask"],
+                tuple(local[f"{name}__seg{j}"] for j in range(nlvl)))
 
 
 def make_distributed_pallas(spec: SpTTNSpec, plan: SpTTNPlan,
@@ -525,12 +511,11 @@ def make_distributed_pallas(spec: SpTTNSpec, plan: SpTTNPlan,
     nfib_static = dict(part.max_nfib)
     order, local_shape = part.order, part.local_shape
     reduce_axes = part.reduce_axes
-    block = ex.block
 
     def local_fn(stacked_local, factors):
         local = {k: v.reshape(v.shape[1:]) for k, v in stacked_local.items()}
         arrays = _unpack_csf(local, order, nfib_static, local_shape)
-        _install_stacked_layouts(arrays, local, manifest, block)
+        _install_stacked_layouts(arrays, local, manifest)
         out = ex(arrays, factors)
         for a in reduce_axes:
             out = jax.lax.psum(out, a)
@@ -617,17 +602,18 @@ class TunedShard:
     tuned plan, the search stats (cache hit/miss accounting), and the
     compiled executor closure.  Only the operand representation the
     shard's backend executes is retained — ``csf`` (host CSFTensor,
-    global coordinates) for ``reference`` replay, ``arrays`` for
-    ``xla``/``pallas`` replay, neither in collective mode (the shard_map
-    engine builds its own stacked layout)."""
+    global coordinates) for ``reference`` replay, the operand bound into
+    ``fn`` on ``device`` for ``xla``/``pallas`` replay, neither in
+    collective mode (the shard_map engine builds its own stacked
+    layout)."""
 
     index: int
     nnz: int
     plan: SpTTNPlan | None       # None for an empty shard
     stats: object | None         # autotune SearchStats
     csf: object | None = None
-    arrays: CSFArrays | None = None
     fn: object | None = None     # factors -> partial output
+    device: object | None = None  # where a replayed shard runs
 
 
 #: the three distributed execution modes a tuned replay can land on
@@ -689,11 +675,12 @@ class DistributedPlanReplay:
             for axis, take in self._undo:
                 out = np.take(out, take, axis=axis)
             return out
+        # dispatch every shard before reading any back: each runs on its
+        # own device, so the programs overlap
+        parts = [sh.fn(factors) for sh in self.shards if sh.fn is not None]
         total = None
-        for sh in self.shards:
-            if sh.fn is None:
-                continue
-            part = np.asarray(sh.fn(factors))
+        for part in parts:
+            part = np.asarray(part)
             total = part if total is None else total + part
         if total is None:       # all shards empty: zero output
             dims = self.spec.dims
@@ -830,6 +817,7 @@ def make_distributed_tuned(spec: SpTTNSpec, coo: COOTensor, mesh: Mesh,
         return dist
 
     _annotate_dist_mode(cache_dir, live, "replay")
+    devices = list(mesh.devices.flat)
     for sh in live:
         pallas_kind = sh.plan.backend in PALLAS_BACKENDS
         kw = dict(executor_kwargs) if pallas_kind else {}
@@ -847,11 +835,10 @@ def make_distributed_tuned(spec: SpTTNSpec, coo: COOTensor, mesh: Mesh,
         if sh.plan.backend == "reference":
             sh.fn = (lambda f, ex=ex, csf=sh.csf: ex(csf, f))
         else:
-            sh.arrays = CSFArrays.from_csf(sh.csf)
-            sh.arrays.host = None    # device arrays suffice for xla/pallas
+            # each shard's operand and program on its own mesh device
+            sh.device = devices[sh.index % len(devices)]
+            sh.fn = jit_bound(ex, sh.csf, device=sh.device)
             sh.csf = None
-            sh.fn = jax.jit(lambda f, ex=ex, arrays=sh.arrays:
-                            ex(arrays, f))
     return dist
 
 

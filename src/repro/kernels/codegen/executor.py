@@ -94,14 +94,18 @@ class SegmentProfile:
 # Entry formats — owned here so every producer agrees with the consumers
 # in ``_fiber_contract`` / ``_exec_chain``:
 #   stage key (lvl, out_lvl, block) ->
-#       (lay, gather, mask[:, None], block_seg, block_first)
+#       (nseg, gather, mask[:, None], block_seg)
 #   chain key ("chain", lvl0, levels, block) ->
-#       (lay, gather, mask[:, None], segs, firsts, lasts[:-1])
-# ``lay`` is consulted only for its static ``nseg`` at trace time; the
-# array slots may be numpy constants (single-device path) OR traced values
-# (the stacked distributed engine pre-populates the cache inside
-# shard_map with per-shard slices of mesh-stacked layouts, which is what
-# lets ONE kernel trace serve every shard).
+#       (nseg, gather, mask[:, None], segs)
+# (the kernels derive a segment's first and last block from the block ->
+# segment maps, so no flag arrays are kept)
+# ``nseg`` is static; the array slots may be numpy (built while tracing
+# over a concrete operand), device arrays (``prepare_operand``), or traced
+# values: a jitted engine receives a prepared operand's layouts as
+# arguments (``CSFArrays`` flattens the cache), and the stacked
+# distributed engine installs per-shard slices of mesh-stacked layouts
+# inside shard_map, which is what lets ONE kernel trace serve every
+# shard.
 # --------------------------------------------------------------------- #
 def layout_cache(csf: CSFArrays) -> dict:
     """The per-operand static layout cache (created on first use)."""
@@ -116,51 +120,44 @@ def chain_layout_key(lvl0: int, levels: tuple, block: int) -> tuple:
     return ("chain", lvl0, tuple(levels), block)
 
 
-def stage_cache_entry(lay, gather, mask, block_seg, block_first) -> tuple:
+def stage_cache_entry(nseg: int, gather, mask, block_seg) -> tuple:
     """Assemble a row-stage cache entry; ``mask`` is the flat (P,) mask
     (the trailing unit lane is added here)."""
-    return (lay, gather, mask[:, None], block_seg, block_first)
+    return (int(nseg), gather, mask[:, None], block_seg)
 
 
-def chain_cache_entry(lay, gather, mask, segs, firsts, lasts) -> tuple:
-    """Assemble a fused-chain cache entry; ``lasts`` excludes the
-    outermost level (the final flush is the grid's end)."""
-    return (lay, gather, mask[:, None], tuple(segs), tuple(firsts),
-            tuple(lasts))
+def chain_cache_entry(nseg: int, gather, mask, segs) -> tuple:
+    """Assemble a fused-chain cache entry."""
+    return (int(nseg), gather, mask[:, None], tuple(segs))
+
+
+def _host_seg(csf, key: tuple) -> np.ndarray:
+    """A segment map as numpy, for building a layout while tracing.  A
+    traced operand carries only the layouts attached before the trace."""
+    seg = csf.seg[key]
+    if isinstance(seg, jax.core.Tracer):
+        raise ValueError(
+            f"no block layout for segment map {key} on this traced "
+            "operand; pass it through prepare_operand(ex, csf, factors) "
+            "before jitting the engine")
+    return np.asarray(seg)
 
 
 def chain_block_arrays(csf, lvl0: int, levels: tuple, block: int):
     """Numpy block-level chain layout: padded innermost layout plus the
-    per-block segment ids / first flags / last flags at every chain
-    level (``lasts`` covers all levels; ``_chain_layout`` drops the
-    outermost).  ``csf`` needs only ``.seg`` and ``.nfib``, so the
-    stacked distributed engine can feed padded per-shard numpy arrays
-    through the same math it would trace with.
+    per-block segment ids at every chain level.  ``csf`` needs only
+    ``.seg`` and ``.nfib``, so the stacked distributed engine can feed
+    padded per-shard numpy arrays through the same math it would trace
+    with.
     """
-    seg0 = np.asarray(csf.seg[(lvl0, levels[0])])
+    seg0 = _host_seg(csf, (lvl0, levels[0]))
     lay = padded_segment_layout(seg0, csf.nfib[levels[0]], block)
-
-    def firsts_of(seg: np.ndarray) -> np.ndarray:
-        f = np.zeros(len(seg), np.int32)
-        f[0] = 1
-        f[1:] = seg[1:] != seg[:-1]
-        return f
-
-    def lasts_of(seg: np.ndarray) -> np.ndarray:
-        l = np.zeros(len(seg), np.int32)
-        l[-1] = 1
-        l[:-1] = seg[1:] != seg[:-1]
-        return l
-
     segs = [lay.block_seg.astype(np.int32)]
     for prev, lvl in zip(levels, levels[1:]):
-        up = (np.asarray(csf.seg[(prev, lvl)])[segs[-1]] if lvl > 0
+        up = (_host_seg(csf, (prev, lvl))[segs[-1]] if lvl > 0
               else np.zeros_like(segs[-1]))
         segs.append(up.astype(np.int32))
-    firsts = [lay.block_first.astype(np.int32)] + \
-        [firsts_of(s) for s in segs[1:]]
-    lasts = [lasts_of(s) for s in segs]
-    return lay, segs, firsts, lasts
+    return lay, segs
 
 
 def segment_profile(csf: CSFArrays, lvl: int, out_lvl: int) -> SegmentProfile:
@@ -255,17 +252,15 @@ class PallasPlanExecutor(VectorizedExecutor):
         cache = layout_cache(csf)
         key = stage_layout_key(lvl, out_lvl, self.block)
         if key not in cache:
-            seg = np.asarray(csf.seg[(lvl, out_lvl)])
+            seg = _host_seg(csf, (lvl, out_lvl))
             nseg = csf.nfib[out_lvl] if out_lvl > 0 else 1
             lay = padded_segment_layout(seg, nseg, self.block)
             # entries stay numpy: an entry first created INSIDE one jit
             # trace must be reusable by a later trace over the same
             # operand (tuner timing several pallas-family candidates), so
-            # nothing trace-bound may be cached here — each trace lifts
-            # the constants itself at the use sites
+            # nothing trace-bound may be cached here
             cache[key] = stage_cache_entry(
-                lay, lay.gather, lay.mask,
-                lay.block_seg, lay.block_first)
+                lay.nseg, lay.gather, lay.mask, lay.block_seg)
         return cache[key]
 
     def strategy_for(self, csf: CSFArrays, lvl: int, out_lvl: int) -> str:
@@ -294,8 +289,8 @@ class PallasPlanExecutor(VectorizedExecutor):
         return len(chain) if chain else 1
 
     def _chain_layout(self, csf: CSFArrays, lvl0: int, levels: tuple):
-        """Per-block segment ids / first flags / last flags at every chain
-        level, plus the padded innermost layout (pattern-static, cached on
+        """Per-block segment ids at every chain level, plus the padded
+        innermost layout (pattern-static, cached on
         the CSFArrays like the single-stage layouts).
 
         ``levels`` are the chain's output levels innermost-first (e.g.
@@ -306,13 +301,10 @@ class PallasPlanExecutor(VectorizedExecutor):
         key = chain_layout_key(lvl0, levels, self.block)
         if key in cache:
             return cache[key]
-        lay, segs, firsts, lasts = chain_block_arrays(csf, lvl0, levels,
-                                                      self.block)
+        lay, segs = chain_block_arrays(csf, lvl0, levels, self.block)
         # numpy, not jnp: see _layout — cache entries outlive any single
         # jit trace, so they must never hold trace-bound values
-        entry = chain_cache_entry(
-            lay, lay.gather, lay.mask,
-            tuple(segs), tuple(firsts), tuple(lasts[:-1]))
+        entry = chain_cache_entry(lay.nseg, lay.gather, lay.mask, segs)
         cache[key] = entry
         return entry
 
@@ -359,8 +351,7 @@ class PallasPlanExecutor(VectorizedExecutor):
         out_subs = "".join(self._letter[i] for i in out_dense0)
         out_shape = tuple(dims[i] for i in out_dense0)
 
-        lay, gather, mask, segs, firsts, lasts = \
-            self._chain_layout(csf, lvl0, levels)
+        nseg0, gather, mask, segs = self._chain_layout(csf, lvl0, levels)
         nfib0 = csf.nfib[lvl0]
         padded = [
             arr.reshape(nfib0, -1)[gather] if op.fiber
@@ -368,7 +359,7 @@ class PallasPlanExecutor(VectorizedExecutor):
             for arr, op in zip(arrays, operands)]
         stage = Stage(operands=tuple(operands), out_subs=out_subs,
                       out_shape=out_shape, reduce=True, block=self.block,
-                      nseg=lay.nseg, interpret=self.interpret,
+                      nseg=nseg0, interpret=self.interpret,
                       tile=self.tile_align)
 
         links, link_arrays = [], []
@@ -402,8 +393,8 @@ class PallasPlanExecutor(VectorizedExecutor):
         self.emitted_stages.append(stage)
         self.emitted_chains.append((stage, tuple(links)))
         self.emitted_ir.append(ir)
-        out2d = self.lowering.chain(ir, segs, firsts, lasts, mask, padded,
-                                    link_arrays, dtype)
+        out2d = self.lowering.chain(ir, segs, mask, padded, link_arrays,
+                                    dtype)
         self.stage_strategy[(lvl0, out_lvl)] = "fused"
         arr = out2d.reshape((nseg_out,) + out_shape)
         if out_lvl == 0:
@@ -437,22 +428,20 @@ class PallasPlanExecutor(VectorizedExecutor):
         out_subs = "".join(self._letter[i] for i in out_dense)
 
         if reduce_ and self._use_row(csf, lvl, out_lvl):
-            lay, gather, mask, block_seg, block_first = \
-                self._layout(csf, lvl, out_lvl)
+            nseg, gather, mask, block_seg = self._layout(csf, lvl, out_lvl)
             padded = [
                 arr.reshape(nfib, -1)[gather] if op.fiber
                 else arr.reshape(1, -1)
                 for arr, op in zip(arrays, operands)]
             stage = Stage(operands=tuple(operands), out_subs=out_subs,
                           out_shape=oshape, reduce=True, block=self.block,
-                          nseg=lay.nseg, interpret=self.interpret,
+                          nseg=nseg, interpret=self.interpret,
                           tile=self.tile_align)
             ir = StageIR(kind="reduce", stage=stage)
             self.emitted_stages.append(stage)
             self.emitted_ir.append(ir)
-            out2d = self.lowering.reduce(ir, block_seg, block_first, mask,
-                                        padded, dtype)
-            arr = out2d.reshape((lay.nseg,) + oshape)
+            out2d = self.lowering.reduce(ir, block_seg, mask, padded, dtype)
+            arr = out2d.reshape((nseg,) + oshape)
             return arr.reshape(oshape) if out_lvl == 0 else arr
 
         # product stage: fused per-fiber contraction; sparse reduction (if

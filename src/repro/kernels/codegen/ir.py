@@ -148,9 +148,9 @@ class StageIR:
     ``kind`` selects the lowering entry point:
 
     * ``"reduce"`` — a row-strategy reducing stage: ``stage`` plus the
-      block layout (``block_seg``/``block_first``/``mask``) supplied at
-      lowering time.  Reset point: a segment's first block; flush point:
-      a segment's last block (both implied by the layout arrays).
+      block layout (``block_seg``/``mask``) supplied at lowering time.
+      Reset point: a segment's first block; flush point: a segment's
+      last block (both implied by the sorted ``block_seg``).
     * ``"product"`` — a per-fiber product stage, blocks 1:1 with output
       blocks (no cross-block state, grid-parallel on every target).
     * ``"chain"`` — a fused reducing chain: innermost ``stage`` plus
@@ -211,27 +211,88 @@ def _check_block_grid(padded_len: int, block: int) -> None:
 
 
 def _load_operands(stage: Stage, in_refs, mask_ref):
-    """Read each operand block and restore its dense shape; the mask is
-    folded into the first fiber operand so pad slots contribute zero.
-    Tile mode slices the padded lanes back off before the reshape, so
-    the einsum always sees exact (unpadded) operands."""
+    """Read each operand block as its 2-D ``(rows, flat)`` value; the mask
+    is folded into the first fiber operand so pad slots contribute zero.
+    Tile mode slices the padded lanes back off, so the contraction always
+    sees exact (unpadded) operands."""
     vals = []
     masked = mask_ref is None
     for ref, op in zip(in_refs, stage.operands):
         v = ref[...]
         if v.shape[-1] != op.flat_dim:
             v = v[:, :op.flat_dim]
-        if op.fiber:
-            v = v.reshape((stage.block,) + op.shape)
-            if not masked:
-                m = mask_ref[...].reshape(
-                    (stage.block,) + (1,) * len(op.shape))
-                v = v * m.astype(v.dtype)
-                masked = True
-        else:
-            v = v.reshape(op.shape)
+        if op.fiber and not masked:
+            v = v * mask_ref[...].astype(v.dtype)
+            masked = True
         vals.append(v)
     return vals
+
+
+def contract_block(operands, out_subs: str, vals, reduce: bool, acc_t):
+    """One block's dense contraction, ``einsum(operands) -> out_subs``, as
+    broadcast-multiply-sum over 2-D ``(rows, flat)`` operand values.
+
+    This is what every lowering runs inside its kernels.  Mosaic has no
+    ``dot_general`` without a contracting dimension (every hadamard and
+    outer product of the SpTTN stages is one), and it cannot split or
+    merge arbitrary lane dimensions.  So each operand is viewed over
+    *groups* of letters: adjacent letters that the same operands carry,
+    in the same order, and that the output keeps or drops alike.  An
+    operand then needs one reshape that splits its lanes per group (a
+    no-op in the common one-group case), the product broadcasts, dropped
+    groups are summed, the kept ones merge back into ``(rows, out_flat)``
+    lanes, and a reducing stage sums its rows.  Fiber operands have
+    ``rows`` = the block, broadcast operands one row.  Returns
+    ``(1 if reduce else rows, prod(out))`` in ``acc_t``.
+    """
+    dim = {}
+    for op in operands:
+        dim.update(zip(op.subs, op.shape))
+    letters = list(out_subs)
+    for op in operands:
+        letters += [c for c in op.subs if c not in letters]
+
+    def sig(c):
+        return tuple(c in op.subs for op in operands), c in out_subs
+
+    groups: list[list[str]] = []
+    for c in letters:
+        prev = groups[-1][-1] if groups else None
+        if prev is not None and sig(prev) == sig(c) and all(
+                op.subs.index(c) == op.subs.index(prev) + 1
+                for op in operands if c in op.subs):
+            groups[-1].append(c)
+        else:
+            groups.append([c])
+    size = [math.prod(dim[c] for c in g) for g in groups]
+
+    prod = None
+    for op, v in zip(operands, vals):
+        v = v.astype(acc_t)
+        rows = v.shape[0]
+        order = [c for c in letters if c in op.subs]
+        if order != list(op.subs):
+            v = v.reshape((rows,) + op.shape)
+            v = jnp.transpose(v, [0] + [1 + op.subs.index(c)
+                                        for c in order])
+        shape = (rows,) + tuple(s if g[0] in op.subs else 1
+                                for g, s in zip(groups, size))
+        if v.shape != shape:
+            v = v.reshape(shape)
+        prod = v if prod is None else prod * v
+    kept = [i for i, g in enumerate(groups) if g[0] in out_subs]
+    dropped = tuple(1 + i for i, g in enumerate(groups)
+                    if g[0] not in out_subs)
+    if dropped:
+        prod = jnp.sum(prod, axis=dropped, keepdims=not kept)
+    out_flat = math.prod(size[i] for i in kept)
+    if prod.shape != (prod.shape[0], out_flat):
+        prod = prod.reshape(prod.shape[0], out_flat)
+    if reduce and prod.shape[0] != 1:
+        # (a one-row sum is the row itself; Mosaic cannot take the rank-1
+        # reshape jnp.sum would emit for it)
+        prod = jnp.sum(prod, axis=0, keepdims=True)
+    return prod
 
 
 # --------------------------------------------------------------------- #
@@ -258,15 +319,14 @@ class Lowering:
 
     target: str = "?"
 
-    def reduce(self, ir: StageIR, block_seg, block_first, mask, padded,
-               dtype):
+    def reduce(self, ir: StageIR, block_seg, mask, padded, dtype):
         raise NotImplementedError
 
     def product(self, ir: StageIR, padded, dtype):
         raise NotImplementedError
 
-    def chain(self, ir: StageIR, seg_lvls, first_lvls, last_lvls, mask,
-              padded, link_arrays, dtype):
+    def chain(self, ir: StageIR, seg_lvls, mask, padded, link_arrays,
+              dtype):
         raise NotImplementedError
 
 

@@ -3,7 +3,7 @@
 The TPU lowering (stages.py) is correct only because TPU grids execute
 sequentially: the output BlockSpec revisits one segment row across all
 of its blocks and a VMEM accumulator carries the partial between grid
-steps, with ``block_first`` firing the Algorithm-2 reset.  GPU grids
+steps, with a segment's first block firing the Algorithm-2 reset.  GPU grids
 give **no such guarantee** — programs launch in parallel and may run in
 any order — so this lowering realizes the same stage IR with a
 different reduce:
@@ -54,7 +54,8 @@ from jax.experimental import pallas as pl
 from repro.kernels.codegen.ir import (Lowering, Stage, StageIR,
                                       _check_block_grid, _lane_padded,
                                       _load_operands, _premask,
-                                      accumulator_type, register_lowering)
+                                      accumulator_type, contract_block,
+                                      register_lowering)
 from repro.kernels.codegen.stages import run_product_stage
 
 
@@ -79,9 +80,9 @@ def splitk_partials(stage: Stage, mask, padded):
         in_refs = refs[(0 if tile else 1):-1]
         o_ref = refs[-1]
         vals = _load_operands(stage, in_refs, m_ref)
-        part = jnp.einsum(stage.expr, *vals, preferred_element_type=acc_t)
-        part = _lane_padded(part.reshape(1, stage.out_flat_dim), out_pad)
-        o_ref[...] = part.astype(o_ref.dtype)
+        part = contract_block(stage.operands, stage.out_subs, vals, True,
+                              acc_t)
+        o_ref[...] = _lane_padded(part, out_pad).astype(o_ref.dtype)
 
     in_specs = []
     if not tile:
@@ -135,11 +136,9 @@ class MosaicGPULowering(Lowering):
 
     target = "gpu"
 
-    def reduce(self, ir: StageIR, block_seg, block_first, mask, padded,
-               dtype):
-        # block_first is the TPU reset trigger; split-K has no resets —
-        # the combine pass owns segment boundaries via block_seg.
-        del block_first
+    def reduce(self, ir: StageIR, block_seg, mask, padded, dtype):
+        # split-K has no resets: the combine pass owns segment boundaries
+        # via block_seg
         parts = splitk_partials(ir.stage, mask, padded)
         return segment_combine(parts, block_seg, ir.stage.nseg) \
             .astype(dtype)
@@ -149,9 +148,8 @@ class MosaicGPULowering(Lowering):
         # shared grid-parallel kernel is already legal on GPU.
         return run_product_stage(ir.stage, padded, dtype)
 
-    def chain(self, ir: StageIR, seg_lvls, first_lvls, last_lvls, mask,
-              padded, link_arrays, dtype):
-        del first_lvls, last_lvls    # TPU reset/flush triggers
+    def chain(self, ir: StageIR, seg_lvls, mask, padded, link_arrays,
+              dtype):
         acc_t = accumulator_type(dtype)
         parts = splitk_partials(ir.stage, mask, padded)
         rows = segment_combine(parts, seg_lvls[0], ir.nseg_lvls[0])

@@ -6,11 +6,13 @@ from __future__ import annotations
 
 import jax
 
+from repro.distributed.sharding import model_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return model_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 2):
@@ -18,4 +20,4 @@ def make_host_mesh(model: int = 2):
     n = len(jax.devices())
     model = min(model, n)
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return model_mesh((data, model), ("data", "model"))

@@ -41,7 +41,7 @@ def main(argv=None):
         mesh = make_host_mesh()
     else:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = SH.model_mesh((d, m), ("data", "model"))
     rules = SH.default_rules(False, "train")
 
     params, specs = model_init(jax.random.PRNGKey(0), cfg)

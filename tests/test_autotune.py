@@ -65,6 +65,44 @@ def test_tuned_runtime_never_worse_than_model(dims, density, seed):
     np.testing.assert_allclose(np.asarray(out), oracle, atol=1e-3)
 
 
+def test_candidates_over_device_memory_are_infeasible(monkeypatch):
+    """A candidate whose program holds one buffer larger than the device
+    (here: the dense fallback that materializes the whole sparse tensor)
+    is never compiled or timed and never wins."""
+    from repro.autotune import measure
+    from repro.core.executor import make_executor, prepare_operand
+    spec, csf, factors = _mttkrp_inputs(64, 48, 40, 8, 0.02, 5)
+    cands = generate_candidates(spec, nnz_levels=csf.nnz_levels(),
+                                max_paths=4, max_candidates=8,
+                                orders_per_path=1)
+    arrays = CSFArrays.from_csf(csf)
+    sizes = {}
+    for c in cands:
+        ex = make_executor(spec, c.path, c.order)
+        sizes[c.key] = measure.largest_buffer_bytes(
+            ex.__call__, prepare_operand(ex, arrays, factors), factors)
+    dense_t = 64 * 48 * 40 * 4
+    limit = dense_t - 1
+    assert min(sizes.values()) <= limit < max(sizes.values())
+    monkeypatch.setattr(measure, "device_bytes_limit", lambda: limit)
+    results = measure.measure_candidates(spec, cands, arrays, factors)
+    assert {m.candidate.key: m.infeasible for m in results} == {
+        k: s > limit for k, s in sizes.items()}
+    tuned, stats = tune(spec, csf=csf, factors=factors, tuner=FAST)
+    assert stats.infeasible >= 1
+    ex = make_executor(spec, tuned.path, tuned.order)
+    assert measure.largest_buffer_bytes(
+        ex.__call__, prepare_operand(ex, arrays, factors), factors) <= limit
+
+
+def test_tune_refuses_when_no_candidate_fits(monkeypatch):
+    from repro.autotune import measure
+    spec, csf, factors = _mttkrp_inputs()
+    monkeypatch.setattr(measure, "device_bytes_limit", lambda: 1)
+    with pytest.raises(ValueError, match="no candidate fits"):
+        tune(spec, csf=csf, factors=factors, tuner=FAST)
+
+
 def test_candidates_are_model_ranked_and_deduped():
     spec, csf, _ = _mttkrp_inputs()
     cands = generate_candidates(spec, nnz_levels=csf.nnz_levels(),

@@ -8,9 +8,8 @@ device-free; (b) ``compressed_psum`` on a 1-shard mesh reduces to an
 (unbiased) quantize/dequantize round trip and is exact on zeros; (c)
 ``reduce_scatter_grads`` falls back to a whole-tensor psum for leaves
 whose leading dim does not divide the axis (subprocess, 4 devices); (d)
-the ``shard_map`` shim routes through both jax APIs — the new
-``jax.shard_map(check_vma=)`` spelling (faked when absent) and the
-``jax.experimental.shard_map(check_rep=)`` one.
+the ``shard_map`` shim passes everything to ``jax.shard_map(check_vma=)``
+and runs a collective.
 """
 import numpy as np
 import pytest
@@ -138,11 +137,11 @@ print("RS-FALLBACK-OK")
 
 
 # --------------------------------------------------------------------- #
-# (d) the shard_map shim: both jax API spellings
+# (d) the shard_map shim
 # --------------------------------------------------------------------- #
 def test_shard_map_shim_new_api_branch(monkeypatch):
-    """When ``jax.shard_map`` exists the shim must call it with
-    ``check_vma`` (the new spelling), passing everything through."""
+    """The shim must call ``jax.shard_map`` with ``check_vma``, passing
+    everything through."""
     seen = {}
 
     def fake(f, mesh, in_specs, out_specs, check_vma):
@@ -158,11 +157,9 @@ def test_shard_map_shim_new_api_branch(monkeypatch):
                     "check_vma": False}
 
 
-def test_shard_map_shim_experimental_branch(monkeypatch):
-    """Without ``jax.shard_map`` the shim must reach the experimental
-    API and translate ``check_vma`` to ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        monkeypatch.delattr(jax, "shard_map")
+def test_shard_map_shim_runs_a_collective():
+    """The shim builds a real shard_map program: a psum over a one-device
+    mesh returns its input."""
     mesh = jax.make_mesh((1,), ("d",))
     fn = jax.jit(collectives.shard_map(
         lambda x: jax.lax.psum(x, "d"), mesh=mesh,

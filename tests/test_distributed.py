@@ -119,7 +119,7 @@ from repro.distributed import sharding as SH
 from repro.models import model_init
 from repro.train.train_step import init_train_state, make_train_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = SH.model_mesh((4, 2), ("data", "model"))
 cfg = get_reduced("granite-moe-1b-a400m")
 params, specs = model_init(jax.random.PRNGKey(0), cfg)
 rules = SH.default_rules(False, "train")

@@ -64,14 +64,18 @@ def test_lowerings_preserve_ir_and_logical_shapes(seed, kernel, density,
         irs[backend] = list(ex.emitted_ir)
     assert irs["pallas"], "no stage IR emitted"
     assert irs["pallas"] == irs["pallas-gpu"]
+    # float32 rounding grows with the output's magnitude (tttc sums reach
+    # ~1e2), so the absolute tolerances scale with it
+    scale = max(1.0, float(np.abs(ref).max()))
     for backend, out in outs.items():
         if spec.output_is_sparse:
             assert out.shape[0] == csf.nnz, backend
         else:
             assert out.shape == ref.shape, backend
-        np.testing.assert_allclose(out, ref, atol=1e-4, err_msg=backend)
+        np.testing.assert_allclose(out, ref, atol=1e-4 * scale,
+                                   err_msg=backend)
     np.testing.assert_allclose(outs["pallas"], outs["pallas-gpu"],
-                               atol=1e-5)
+                               atol=1e-5 * scale)
 
 
 @settings(max_examples=50, deadline=None)
@@ -92,9 +96,12 @@ def test_segment_combine_is_bitexact_sequential_accumulation(
     # magnitude spread makes float addition order-observable, so the
     # bit-for-bit assertion below really pins the order
     parts *= 10.0 ** rng.integers(-6, 7, size=(len(seg), 1))
-    from jax.experimental import enable_x64
-    with enable_x64():
+    import jax
+    jax.config.update("jax_enable_x64", True)
+    try:
         got = np.asarray(segment_combine(parts, seg, nseg))
+    finally:
+        jax.config.update("jax_enable_x64", False)
     want = np.zeros((nseg, width), np.float64)
     for b in range(len(seg)):             # ascending block order
         want[seg[b]] = want[seg[b]] + parts[b]
