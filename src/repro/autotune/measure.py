@@ -20,6 +20,7 @@ from its first call catches the rest.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
@@ -30,6 +31,7 @@ import numpy as np
 from repro.analysis.diagnostics import PALLAS_BACKENDS
 from repro.autotune.candidates import Candidate
 from repro.core.spec import SpTTNSpec
+from repro.spans import span
 
 
 @dataclasses.dataclass
@@ -110,11 +112,14 @@ def measure_candidates(spec: SpTTNSpec,
 
     ``arrays`` is a device-resident :class:`CSFArrays`.  ``stats`` (a
     :class:`~repro.autotune.tuner.SearchStats`) is incremented in place so
-    callers can assert how much empirical work a search performed.
+    callers can assert how much empirical work a search performed, and
+    where its seconds went (spans ``tune.prepare``, ``tune.warmup``,
+    ``tune.time`` per candidate) and the largest layout a candidate built.
     """
     import jax
 
-    from repro.core.executor import make_executor, prepare_operand
+    from repro.core.executor import (layout_bytes, make_executor,
+                                     prepare_operand)
 
     config = config or MeasureConfig()
     results: list[Measurement] = []
@@ -146,18 +151,26 @@ def measure_candidates(spec: SpTTNSpec,
         # a fresh view of the operand per candidate, so the layouts one
         # candidate attaches never ride into (or stay resident for)
         # another's program
-        operand = prepare_operand(ex, dataclasses.replace(arrays), factors)
-        if limit is not None and largest_buffer_bytes(
-                ex.__call__, operand, factors) > limit:
+        with phase("tune.prepare", stats, "prepare_seconds"):
+            operand = prepare_operand(ex, dataclasses.replace(arrays),
+                                      factors)
+            fits = limit is None or largest_buffer_bytes(
+                ex.__call__, operand, factors) <= limit
+        if stats is not None:
+            stats.layout_bytes_max = max(stats.layout_bytes_max,
+                                         layout_bytes(operand))
+        if not fits:
             infeasible(cand)
             continue
         jitted = jax.jit(ex.__call__)
         fn = (lambda f, jitted=jitted, operand=operand:
               jitted(operand, f))
         try:
-            for _ in range(config.warmup):
-                run(fn)
-            first = run(fn)
+            with phase("tune.warmup", stats, "warmup_seconds"):
+                for _ in range(config.warmup):
+                    run(fn)
+            with phase("tune.time", stats, "time_seconds"):
+                first = run(fn)
         except jax.errors.JaxRuntimeError as e:
             if "RESOURCE_EXHAUSTED" not in str(e):
                 raise
@@ -171,7 +184,8 @@ def measure_candidates(spec: SpTTNSpec,
             if stats is not None:
                 stats.pruned += 1
             continue
-        times = [first] + [run(fn) for _ in range(config.repeats - 1)]
+        with phase("tune.time", stats, "time_seconds"):
+            times = [first] + [run(fn) for _ in range(config.repeats - 1)]
         med = float(np.median(times))
         results.append(Measurement(cand, med))
         best = med if best is None else min(best, med)
@@ -182,3 +196,16 @@ def measure_candidates(spec: SpTTNSpec,
     # ones, with no sample at all, last)
     results.sort(key=lambda m: (m.infeasible, m.pruned, m.seconds))
     return results
+
+
+@contextlib.contextmanager
+def phase(name: str, stats, field: str):
+    """Span ``name``, whose seconds ``stats.<field>`` (a
+    :class:`~repro.autotune.tuner.SearchStats` field) adds up, also when
+    the block raises."""
+    try:
+        with span(name) as s:
+            yield
+    finally:
+        if stats is not None:
+            setattr(stats, field, getattr(stats, field) + s.seconds)

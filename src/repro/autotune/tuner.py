@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections.abc import Mapping
+from typing import ClassVar
 
 from repro.analysis.diagnostics import PALLAS_BACKENDS
 from repro.autotune.cache import (PlanCache, bucket_nnz_levels,
@@ -22,7 +23,7 @@ from repro.autotune.cache import (PlanCache, bucket_nnz_levels,
 from repro.autotune.candidates import (default_nnz_levels,
                                        generate_candidates)
 from repro.autotune.measure import (MeasureConfig, measure_candidates,
-                                    synth_factors, synth_inputs)
+                                    phase, synth_factors, synth_inputs)
 from repro.core.cost import ConstrainedBlas, TreeCost
 from repro.core.spec import SpTTNSpec
 
@@ -101,8 +102,22 @@ class SearchStats:
     """What one ``tune`` call actually did (assertable by tests/benchmarks).
 
     ``executions`` counts every measured kernel launch, warmup included —
-    a cache hit performs none.
+    a cache hit performs none.  The ``TRACED`` fields say where a
+    search's seconds went, each the total of one span: the cache lookup
+    (``tune.cache``), candidate generation with ``verify_plan``
+    (``tune.generate``) and the measurement (``tune.measure``), which
+    per candidate splits into building its operand and checking its fit
+    (``tune.prepare``), its first calls, compile included
+    (``tune.warmup``), and its timed calls (``tune.time``).
+    ``layout_bytes_max`` is the largest block layout one candidate built.
+    A search writes the ``TRACED`` fields to its plan-cache entry's
+    ``meta`` too.
     """
+
+    TRACED: ClassVar[tuple[str, ...]] = (
+        "cache_seconds", "generate_seconds", "measure_seconds",
+        "prepare_seconds", "warmup_seconds", "time_seconds",
+        "layout_bytes_max")
 
     cache_hit: bool = False
     cache_key: str = ""
@@ -120,6 +135,13 @@ class SearchStats:
     search_seconds: float = 0.0
     best_seconds: float | None = None
     model_seconds: float | None = None   # measured time of the model's pick
+    cache_seconds: float = 0.0
+    generate_seconds: float = 0.0
+    measure_seconds: float = 0.0
+    prepare_seconds: float = 0.0
+    warmup_seconds: float = 0.0
+    time_seconds: float = 0.0
+    layout_bytes_max: int = 0
 
 
 def _bucket_reuse_ok(plan, spec: SpTTNSpec, true_levels: Mapping[int, int],
@@ -212,42 +234,44 @@ def tune(spec: SpTTNSpec,
         return stamp_plan_slicing(p, levels, memory_budget)
 
     if cache is not None:
-        hit = cache.get(key)         # exact-key fast path
+        with phase("tune.cache", stats, "cache_seconds"):
+            hit = cache.get(key)         # exact-key fast path
+            if hit is None and bkey is not None:
+                hit = cache.get(bkey)
+                if hit is not None and _bucket_reuse_ok(hit, spec, levels,
+                                                        config, stats):
+                    stats.bucket_hit = True
+                else:
+                    hit = None
         if hit is not None:
             stats.cache_hit = True
             stats.search_seconds = time.perf_counter() - t_start
             return _budgeted(hit), stats
-        if bkey is not None:
-            hit = cache.get(bkey)
-            if hit is not None and _bucket_reuse_ok(hit, spec, levels,
-                                                    config, stats):
-                stats.cache_hit = True
-                stats.bucket_hit = True
-                stats.search_seconds = time.perf_counter() - t_start
-                return _budgeted(hit), stats
 
     # --- model-side pruning ------------------------------------------- #
     # generate_candidates ranks by TreeCost.evaluate (the ground-truth
     # scale Algorithm 1 optimizes, dense-term offset included), so the
     # ranking head IS the pure-model pick — it is always measured, which
     # guarantees tuned-runtime <= model-runtime on these measurements.
-    candidates = generate_candidates(
-        spec, cost=cost, nnz_levels=levels, max_paths=config.max_paths,
-        depth_slack=config.depth_slack,
-        max_candidates=config.max_candidates,
-        orders_per_path=config.orders_per_path,
-        backends=backends, blocks=config.blocks)
-    stats.candidates_generated = len(candidates)
-
-    # --- static verification gate ------------------------------------- #
-    # an E-severity diagnostic means some engine would reject (or
-    # miscompute) the schedule — never spend compile+measure time on it.
-    # Today's generator emits only legal candidates, so this prunes
-    # nothing; it is the contract future candidate sources inherit.
     from repro.analysis import verify_plan
-    legal = [c for c in candidates
-             if verify_plan(spec, c.path, c.order, backend=c.backend,
-                            fused=c.fused, block=c.block or None).ok]
+    with phase("tune.generate", stats, "generate_seconds"):
+        candidates = generate_candidates(
+            spec, cost=cost, nnz_levels=levels, max_paths=config.max_paths,
+            depth_slack=config.depth_slack,
+            max_candidates=config.max_candidates,
+            orders_per_path=config.orders_per_path,
+            backends=backends, blocks=config.blocks)
+        stats.candidates_generated = len(candidates)
+
+        # --- static verification gate --------------------------------- #
+        # an E-severity diagnostic means some engine would reject (or
+        # miscompute) the schedule — never spend compile+measure time on
+        # it.  Today's generator emits only legal candidates, so this
+        # prunes nothing; it is the contract future candidate sources
+        # inherit.
+        legal = [c for c in candidates
+                 if verify_plan(spec, c.path, c.order, backend=c.backend,
+                                fused=c.fused, block=c.block or None).ok]
     stats.vetoed = len(candidates) - len(legal)
     if not legal:
         raise ValueError(
@@ -262,8 +286,9 @@ def tune(spec: SpTTNSpec,
               else CSFArrays.from_csf(csf))
     mcfg = MeasureConfig(warmup=config.warmup, repeats=config.repeats,
                          prune_ratio=config.prune_ratio)
-    results = measure_candidates(spec, candidates, arrays, factors,
-                                 config=mcfg, stats=stats)
+    with phase("tune.measure", stats, "measure_seconds"):
+        results = measure_candidates(spec, candidates, arrays, factors,
+                                     config=mcfg, stats=stats)
     # winner selection skips pruned entries explicitly: a pruned
     # measurement is one first-call sample, not a median, and must never
     # win (measure_candidates sorts them last, but the skip is the
@@ -300,6 +325,7 @@ def tune(spec: SpTTNSpec,
             "model_seconds": stats.model_seconds,
             "candidates_timed": stats.candidates_timed,
             "executions": stats.executions,
+            **{f: getattr(stats, f) for f in SearchStats.TRACED},
             "device": device,
             "backends": list(backends),
             "mesh": None if config.mesh is None else dict(config.mesh),

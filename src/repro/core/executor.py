@@ -44,6 +44,7 @@ from repro.core.loopnest import LoopOrder, buffer_indices
 from repro.core.paths import ContractionPath, Term, consumer_map
 from repro.core.spec import SpTTNSpec
 from repro.sparse.csf import CSFTensor, level_segments
+from repro.spans import count, span
 
 # The three execution engines (DESIGN.md §3/§6) live in ``BACKENDS``,
 # owned by the static verifier (repro.analysis.invariants) and
@@ -361,7 +362,12 @@ class CSFArrays:
     host: "CSFTensor | None" = None   # source tensor (reference engine)
 
     @classmethod
+    @span("csf.upload")
     def from_csf(cls, csf: CSFTensor) -> "CSFArrays":
+        """Derive the fiber coordinates and segment maps on the host and
+        start their upload with the values (span ``csf.upload``, which
+        does not wait for the transfers; their bytes are counted in
+        ``csf.upload_bytes``)."""
         fiber_coord: dict[int, dict[int, jnp.ndarray]] = {}
         for p in range(1, csf.order + 1):
             fc = csf.fiber_coords(p)
@@ -371,10 +377,13 @@ class CSFArrays:
             for par in range(0, child):
                 seg[(child, par)] = jnp.asarray(
                     level_segments(csf, child, par))
-        return cls(values=jnp.asarray(csf.values),
-                   fiber_coord=fiber_coord, seg=seg,
-                   nfib=dict(csf.nfib), order=csf.order,
-                   shape=csf.shape, host=csf)
+        out = cls(values=jnp.asarray(csf.values),
+                  fiber_coord=fiber_coord, seg=seg,
+                  nfib=dict(csf.nfib), order=csf.order,
+                  shape=csf.shape, host=csf)
+        count("csf.upload_bytes",
+              sum(x.nbytes for x in jax.tree.leaves(out)))
+        return out
 
     def tree_flatten(self):
         layouts = self.__dict__.get("_codegen_layouts", {})
@@ -415,6 +424,13 @@ def prepare_operand(ex, csf, factors: Mapping) -> CSFArrays:
     return arrays
 
 
+def layout_bytes(arrays: CSFArrays) -> int:
+    """Bytes of the block layouts attached to ``arrays``."""
+    layouts = arrays.__dict__.get("_codegen_layouts", {})
+    return sum(x.nbytes for e in layouts.values()
+               for x in jax.tree.leaves(e[1:]))
+
+
 def jit_bound(ex, csf, device=None):
     """``factors -> ex(csf, factors)``, jitted, with the operand passed
     as an argument (:func:`prepare_operand`, on the first call, when the
@@ -436,11 +452,35 @@ def jit_bound(ex, csf, device=None):
     return call
 
 
+def key_compile_cache_by_metadata() -> None:
+    """Make op metadata part of JAX's persistent compilation cache key.
+
+    The term scopes live only in op metadata, which the key leaves out by
+    default.  A cache shared with programs that had the same ops under
+    other scopes, or none (another checkout, an older build), would then
+    hand back their executables, and a device trace would attribute time
+    to the wrong terms, or to none.
+
+    The setting is JAX's, so it holds for every program the process
+    compiles from then on, SpTTN or not.  Its cost falls on processes
+    that keep a persistent cache: the metadata holds source files and
+    lines, so an edit that moves a traced line, or the same code at
+    another path, compiles anew instead of hitting the cache.
+    """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
+
 class VectorizedExecutor:
     """Compile a (path, order) plan into a JAX function over CSF arrays.
 
     The plan's fused sparse depth per intermediate decides the CSF level at
     which it is materialized; trailing dense loops become one einsum.
+
+    Every op a term lowers to carries a named scope ``t<i>.<kind>`` in
+    its metadata (:meth:`_scope`), and the output's materialization the
+    scope ``out``, so a device trace attributes time to loop-nest terms.
+    Building an engine keys JAX's persistent compilation cache by that
+    metadata too (:func:`key_compile_cache_by_metadata`).
     """
 
     def __init__(self, spec: SpTTNSpec, path: ContractionPath,
@@ -451,6 +491,8 @@ class VectorizedExecutor:
         self.spos = {s: i for i, s in enumerate(spec.sparse_indices)}
         from repro.core.loopnest import fused_sparse_depth
         self.fuse_depth = fused_sparse_depth(path, order, spec.sparse_indices)
+        self._tid = 0      # term being lowered (named scopes, _scope)
+        key_compile_cache_by_metadata()
         self._letter = {}
         for i in spec.all_indices:
             self._letter[i] = string.ascii_lowercase[len(self._letter)]
@@ -459,6 +501,15 @@ class VectorizedExecutor:
     def _sparse_level(self, inds: Sequence[str]) -> int:
         return max((self.spos[i] + 1 for i in inds if i in self.spos),
                    default=0)
+
+    def _scope(self, kind: str):
+        """``jax.named_scope`` ``t<i>.<kind>`` of the term being lowered
+        (``_tid``, set while tracing): ``lift`` for gathers onto fibers,
+        ``contract`` for the per-fiber einsum, ``reduce`` for segmented
+        sums, ``scatter`` for a final scatter-add, ``dense`` for the dense
+        fallback, ``stage.<kind>`` for a generated Pallas stage.  Compile-
+        time metadata only: the program's ops are unchanged."""
+        return jax.named_scope(f"t{self._tid}.{kind}")
 
     def _is_prefix(self, inds: Sequence[str]) -> bool:
         """True if the sparse indices of ``inds`` form a CSF storage prefix."""
@@ -567,9 +618,10 @@ class VectorizedExecutor:
         # dense fallback (covers dense x dense and non-prefix cases)
         ai = tuple(term.lhs.indices)
         bi = tuple(term.rhs.indices)
-        da = self._to_dense(csf, a, ai)
-        db = self._to_dense(csf, b, bi)
-        arr = self._einsum(da, ai, db, bi, out_inds, fiber=False)
+        with self._scope("dense"):
+            da = self._to_dense(csf, a, ai)
+            db = self._to_dense(csf, b, bi)
+            arr = self._einsum(da, ai, db, bi, out_inds, fiber=False)
         return DenseVal(arr, out_inds)
 
     def _materialize_output(self, csf: CSFArrays,
@@ -589,6 +641,7 @@ class VectorizedExecutor:
         env: dict[str, FiberVal | DenseVal] = {}
         tid, n = 0, len(self.path)
         while tid < n:
+            self._tid = tid
             length = self._chain_len(tid)
             if length > 1:
                 val = self._exec_chain(csf, factors, env, tid, length)
@@ -599,19 +652,21 @@ class VectorizedExecutor:
                 val = self._exec_term(csf, factors, env, term)
                 tid += 1
             if term.out.name == "OUT":
-                return self._materialize_output(csf, val)
+                with jax.named_scope("out"):
+                    return self._materialize_output(csf, val)
             env[term.out.name] = val
         raise AssertionError("path had no final term")
 
     # ------------------------------------------------------------------ #
     def _lift(self, csf: CSFArrays, v, ref, lvl: int):
         """Bring an operand onto level-``lvl`` fibers."""
-        if isinstance(v, FiberVal):
-            arr = v.array
-            if v.level < lvl:
-                arr = arr[csf.seg[(lvl, v.level)]]
-            return arr, v.dense
-        return self._lift_dense_factor(csf, v.array, ref.indices, lvl)
+        with self._scope("lift"):
+            if isinstance(v, FiberVal):
+                arr = v.array
+                if v.level < lvl:
+                    arr = arr[csf.seg[(lvl, v.level)]]
+                return arr, v.dense
+            return self._lift_dense_factor(csf, v.array, ref.indices, lvl)
 
     def _exec_final_scatter(self, csf: CSFArrays, term: Term, a, b):
         """Final term whose kept sparse indices are not a storage prefix:
@@ -628,10 +683,11 @@ class VectorizedExecutor:
         shape = [spec.dims[i] for i in out_sp] + \
             [spec.dims[i] for i in out_dense]
         full = tuple(out_sp) + out_dense
-        out = jnp.zeros(shape, arr.dtype).at[coords].add(arr)
         perm = [full.index(i) for i in out_inds]
-        return jnp.transpose(out, perm) if perm != list(range(len(perm))) \
-            else out
+        with self._scope("scatter"):
+            out = jnp.zeros(shape, arr.dtype).at[coords].add(arr)
+            return jnp.transpose(out, perm) \
+                if perm != list(range(len(perm))) else out
 
     def _exec_fiber_term(self, csf: CSFArrays, term: Term,
                          a: "FiberVal | DenseVal",
@@ -662,18 +718,21 @@ class VectorizedExecutor:
         means no sparse reduction (per-fiber output); ``out_lvl == 0``
         returns the dense array of shape ``out_dense``.
         """
-        arr = self._einsum(fa, da, fb, db, out_dense, fiber=True)
+        with self._scope("contract"):
+            arr = self._einsum(fa, da, fb, db, out_dense, fiber=True)
         if out_lvl < lvl:
-            seg = csf.seg[(lvl, out_lvl)] if out_lvl > 0 else jnp.zeros(
-                arr.shape[0], jnp.int32)
-            nseg = csf.nfib[out_lvl] if out_lvl > 0 else 1
-            # CSF order is lexicographic: segment ids are sorted, which
-            # lets XLA lower the reduction as a contiguous segmented scan
-            # instead of a scatter (§Perf wall-clock iteration 1)
-            arr = jax.ops.segment_sum(arr, seg, num_segments=nseg,
-                                      indices_are_sorted=True)
-            if out_lvl == 0:
-                arr = arr[0]
+            with self._scope("reduce"):
+                seg = csf.seg[(lvl, out_lvl)] if out_lvl > 0 else \
+                    jnp.zeros(arr.shape[0], jnp.int32)
+                nseg = csf.nfib[out_lvl] if out_lvl > 0 else 1
+                # CSF order is lexicographic: segment ids are sorted,
+                # which lets XLA lower the reduction as a contiguous
+                # segmented scan instead of a scatter (§Perf wall-clock
+                # iteration 1)
+                arr = jax.ops.segment_sum(arr, seg, num_segments=nseg,
+                                          indices_are_sorted=True)
+                if out_lvl == 0:
+                    arr = arr[0]
         return arr
 
 

@@ -329,6 +329,7 @@ class PallasPlanExecutor(VectorizedExecutor):
             # degenerate pattern: fall back to the staged per-term path
             val = None
             for k in tids:
+                self._tid = k
                 val = self._exec_term(csf, factors, env, self.path[k])
                 if k != tids[-1]:
                     env[self.path[k].out.name] = val
@@ -353,10 +354,11 @@ class PallasPlanExecutor(VectorizedExecutor):
 
         nseg0, gather, mask, segs = self._chain_layout(csf, lvl0, levels)
         nfib0 = csf.nfib[lvl0]
-        padded = [
-            arr.reshape(nfib0, -1)[gather] if op.fiber
-            else arr.reshape(1, -1)
-            for arr, op in zip(arrays, operands)]
+        with self._scope("lift"):
+            padded = [
+                arr.reshape(nfib0, -1)[gather] if op.fiber
+                else arr.reshape(1, -1)
+                for arr, op in zip(arrays, operands)]
         stage = Stage(operands=tuple(operands), out_subs=out_subs,
                       out_shape=out_shape, reduce=True, block=self.block,
                       nseg=nseg0, interpret=self.interpret,
@@ -393,12 +395,15 @@ class PallasPlanExecutor(VectorizedExecutor):
         self.emitted_stages.append(stage)
         self.emitted_chains.append((stage, tuple(links)))
         self.emitted_ir.append(ir)
-        out2d = self.lowering.chain(ir, segs, mask, padded, link_arrays,
-                                    dtype)
+        with self._scope("stage.chain"):
+            out2d = self.lowering.chain(ir, segs, mask, padded, link_arrays,
+                                        dtype)
+            arr = out2d.reshape((nseg_out,) + out_shape)
+            if out_lvl == 0:
+                arr = arr.reshape(out_shape)
         self.stage_strategy[(lvl0, out_lvl)] = "fused"
-        arr = out2d.reshape((nseg_out,) + out_shape)
         if out_lvl == 0:
-            return DenseVal(arr.reshape(out_shape), out_dense)
+            return DenseVal(arr, out_dense)
         return FiberVal(arr, out_lvl, out_dense)
 
     # -- the lowering unit ---------------------------------------------- #
@@ -429,10 +434,11 @@ class PallasPlanExecutor(VectorizedExecutor):
 
         if reduce_ and self._use_row(csf, lvl, out_lvl):
             nseg, gather, mask, block_seg = self._layout(csf, lvl, out_lvl)
-            padded = [
-                arr.reshape(nfib, -1)[gather] if op.fiber
-                else arr.reshape(1, -1)
-                for arr, op in zip(arrays, operands)]
+            with self._scope("lift"):
+                padded = [
+                    arr.reshape(nfib, -1)[gather] if op.fiber
+                    else arr.reshape(1, -1)
+                    for arr, op in zip(arrays, operands)]
             stage = Stage(operands=tuple(operands), out_subs=out_subs,
                           out_shape=oshape, reduce=True, block=self.block,
                           nseg=nseg, interpret=self.interpret,
@@ -440,20 +446,23 @@ class PallasPlanExecutor(VectorizedExecutor):
             ir = StageIR(kind="reduce", stage=stage)
             self.emitted_stages.append(stage)
             self.emitted_ir.append(ir)
-            out2d = self.lowering.reduce(ir, block_seg, mask, padded, dtype)
-            arr = out2d.reshape((nseg,) + oshape)
-            return arr.reshape(oshape) if out_lvl == 0 else arr
+            with self._scope("stage.reduce"):
+                out2d = self.lowering.reduce(ir, block_seg, mask, padded,
+                                             dtype)
+                arr = out2d.reshape((nseg,) + oshape)
+                return arr.reshape(oshape) if out_lvl == 0 else arr
 
         # product stage: fused per-fiber contraction; sparse reduction (if
         # any) stays an XLA segmented scan over sorted CSF segment ids
         P = round_up(nfib, self.block)
         padded = []
-        for arr, op in zip(arrays, operands):
-            if op.fiber:
-                flat = arr.reshape(nfib, -1)
-                padded.append(jnp.pad(flat, ((0, P - nfib), (0, 0))))
-            else:
-                padded.append(arr.reshape(1, -1))
+        with self._scope("lift"):
+            for arr, op in zip(arrays, operands):
+                if op.fiber:
+                    flat = arr.reshape(nfib, -1)
+                    padded.append(jnp.pad(flat, ((0, P - nfib), (0, 0))))
+                else:
+                    padded.append(arr.reshape(1, -1))
         stage = Stage(operands=tuple(operands), out_subs=out_subs,
                       out_shape=oshape, reduce=False, block=self.block,
                       nseg=0, interpret=self.interpret,
@@ -461,14 +470,16 @@ class PallasPlanExecutor(VectorizedExecutor):
         ir = StageIR(kind="product", stage=stage)
         self.emitted_stages.append(stage)
         self.emitted_ir.append(ir)
-        per_fiber = self.lowering.product(ir, padded, dtype)
-        arr = per_fiber[:nfib].reshape((nfib,) + oshape)
+        with self._scope("stage.product"):
+            per_fiber = self.lowering.product(ir, padded, dtype)
+            arr = per_fiber[:nfib].reshape((nfib,) + oshape)
         if reduce_:
-            seg = csf.seg[(lvl, out_lvl)] if out_lvl > 0 else jnp.zeros(
-                nfib, jnp.int32)
-            nseg = csf.nfib[out_lvl] if out_lvl > 0 else 1
-            arr = jax.ops.segment_sum(arr, seg, num_segments=nseg,
-                                      indices_are_sorted=True)
-            if out_lvl == 0:
-                arr = arr[0]
+            with self._scope("reduce"):
+                seg = csf.seg[(lvl, out_lvl)] if out_lvl > 0 else \
+                    jnp.zeros(nfib, jnp.int32)
+                nseg = csf.nfib[out_lvl] if out_lvl > 0 else 1
+                arr = jax.ops.segment_sum(arr, seg, num_segments=nseg,
+                                          indices_are_sorted=True)
+                if out_lvl == 0:
+                    arr = arr[0]
         return arr

@@ -178,6 +178,7 @@ def run_reduce_stage(stage: Stage, block_seg: jnp.ndarray,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((stage.nseg, 1, out_pad), dtype),
         interpret=stage.interpret,
+        name="spttn_reduce",
     )(block_seg, *inputs)
     out = out.reshape(stage.nseg, out_pad)
     return out[:, :stage.out_flat_dim] if out_pad != stage.out_flat_dim \
@@ -220,6 +221,7 @@ def run_product_stage(stage: Stage, padded, dtype) -> jnp.ndarray:
                                lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((P, out_pad), dtype),
         interpret=stage.interpret,
+        name="spttn_product",
     )(*padded)
     return out[:, :stage.out_flat_dim] if out_pad != stage.out_flat_dim \
         else out
@@ -350,6 +352,7 @@ def run_fused_chain_stage(stage: Stage, links: tuple[ChainLink, ...],
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((nseg_out, 1, out_pad), dtype),
         interpret=stage.interpret,
+        name="spttn_chain",
     )(*seg_lvls, *inputs)
     out = out.reshape(nseg_out, out_pad)
     # an output row whose segment owns no block is never stored by the

@@ -11,6 +11,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.spans import span
+
 
 @dataclasses.dataclass
 class COOTensor:
@@ -33,6 +35,7 @@ class COOTensor:
         out[tuple(self.coords.T)] = self.values
         return out
 
+    @span("coo.sort")
     def permute_modes(self, perm: tuple[int, ...]) -> "COOTensor":
         coords = self.coords[:, list(perm)]
         shape = tuple(self.shape[p] for p in perm)

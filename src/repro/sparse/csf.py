@@ -19,6 +19,7 @@ import dataclasses
 import numpy as np
 
 from repro.sparse.coo import COOTensor
+from repro.spans import span
 
 
 @dataclasses.dataclass
@@ -70,6 +71,7 @@ class CSFTensor:
         return out
 
 
+@span("csf.levels")
 def build_csf(coo: COOTensor) -> CSFTensor:
     """One-time host-side construction (sparsity is fixed — paper §1)."""
     coords = coo.coords
@@ -100,6 +102,7 @@ def build_csf(coo: COOTensor) -> CSFTensor:
     return CSFTensor(coo=coo, coord=coord, parent=parent, seg=seg, nfib=nfib)
 
 
+@span("csf.levels")
 def build_csf_batch(coos: "list[COOTensor] | tuple[COOTensor, ...]"
                     ) -> list[CSFTensor]:
     """Amortized CSF construction for a *request batch* (DESIGN.md §9).

@@ -39,6 +39,7 @@ API_MODULES = [
     "repro.autotune.cache",
     "repro.autotune.tuner",
     "repro.distributed.spttn_dist",
+    "repro.spans",
 ]
 
 

@@ -12,6 +12,8 @@ The topology is described inside a module fixture (never at import): only
 one process may load the TPU library, and the test workers all import
 this file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -87,3 +89,22 @@ def test_pallas_engine_compiles_for_v5e(kernel, strategy, one_chip):
 def test_xla_engine_compiles_for_v5e(kernel, one_chip):
     _, compiled = _compile(kernel, one_chip, "xla")
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("strategy,kind", [("row", "reduce"),
+                                           ("segsum", "product"),
+                                           ("fused", "chain")])
+def test_pallas_stages_carry_their_name_and_term_scope(strategy, kind,
+                                                       one_chip):
+    """Each Mosaic kernel is named for its stage kind (``spttn_<kind>``)
+    and sits under its term's scope ``t<i>.stage.<kind>``, which the
+    chip trace keeps."""
+    _, compiled = _compile("mttkrp-r16", one_chip, "pallas",
+                           interpret=False, tile_align=True,
+                           strategy=strategy)
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert kernels
+    for line in kernels:
+        assert re.search(rf'op_name="[^"]*/t\d+\.stage\.{kind}/', line)
+        assert f"spttn_{kind}" in line
