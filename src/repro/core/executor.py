@@ -721,19 +721,35 @@ class VectorizedExecutor:
         with self._scope("contract"):
             arr = self._einsum(fa, da, fb, db, out_dense, fiber=True)
         if out_lvl < lvl:
-            with self._scope("reduce"):
-                seg = csf.seg[(lvl, out_lvl)] if out_lvl > 0 else \
-                    jnp.zeros(arr.shape[0], jnp.int32)
-                nseg = csf.nfib[out_lvl] if out_lvl > 0 else 1
-                # CSF order is lexicographic: segment ids are sorted,
-                # which lets XLA lower the reduction as a contiguous
-                # segmented scan instead of a scatter (§Perf wall-clock
-                # iteration 1)
-                arr = jax.ops.segment_sum(arr, seg, num_segments=nseg,
-                                          indices_are_sorted=True)
-                if out_lvl == 0:
-                    arr = arr[0]
+            arr = self._segment_sum(csf, arr, lvl, out_lvl)
         return arr
+
+    def _segment_sum(self, csf: CSFArrays, arr: jnp.ndarray, lvl: int,
+                     out_lvl: int) -> jnp.ndarray:
+        """Sum level-``lvl`` fiber rows into their level-``out_lvl``
+        parents (``out_lvl == 0``: into one row, returned without its
+        fiber axis), under the term's ``reduce`` scope.
+
+        CSF order is lexicographic, so the segment ids are sorted.  On
+        TPU a float32 reduction whose operand carries its merge path
+        (:func:`repro.kernels.segment_sum.attached_work`) runs the
+        one-hot kernel and counts ``reduce.kernel``; any other runs XLA's
+        sorted ``segment_sum`` and counts ``reduce.scatter``.
+        """
+        from repro.kernels import segment_sum as ss
+        with self._scope("reduce"):
+            work = ss.attached_work(csf, lvl, out_lvl, arr.dtype)
+            if work is not None:
+                count("reduce.kernel", 1)
+                return ss.sorted_segment_sum(arr, csf.seg[(lvl, out_lvl)],
+                                             csf.nfib[out_lvl], work)
+            count("reduce.scatter", 1)
+            seg = csf.seg[(lvl, out_lvl)] if out_lvl > 0 else \
+                jnp.zeros(arr.shape[0], jnp.int32)
+            nseg = csf.nfib[out_lvl] if out_lvl > 0 else 1
+            arr = jax.ops.segment_sum(arr, seg, num_segments=nseg,
+                                      indices_are_sorted=True)
+            return arr[0] if out_lvl == 0 else arr
 
 
 def execute_unfactorized(spec: SpTTNSpec, csf: CSFArrays,

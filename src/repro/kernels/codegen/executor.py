@@ -4,8 +4,8 @@ Structural sibling of :class:`~repro.core.executor.VectorizedExecutor`
 (it *is* one, by inheritance): operand lifting, dense fallbacks, and
 final-output materialization are shared, so the two engines agree by
 construction everywhere except the lowering unit — ``_fiber_contract``,
-where the XLA engine's einsum + ``segment_sum`` is replaced by generated
-Pallas stages.  The executor emits *target-neutral* stage IR
+where the XLA engine's einsum + sorted segment sum is replaced by
+generated Pallas stages.  The executor emits *target-neutral* stage IR
 (kernels/codegen/ir.py) and hands it to the registered
 :class:`~repro.kernels.codegen.ir.Lowering` for its ``target``:
 ``"tpu"`` (stages.py, sequential-grid VMEM accumulator — the
@@ -22,8 +22,9 @@ static segment profile (pattern-known, so the choice is trace-time):
   with the Algorithm-2 reset.  Chosen when segments are block-sized —
   padding stays bounded.
 * **segsum** — a fused product stage (hadamard/dot in VMEM) followed by
-  an XLA segmented sum.  Chosen when segments are tiny (e.g. leaf ->
-  next level), where block-per-segment padding would explode.
+  the engines' sorted segment sum (``VectorizedExecutor._segment_sum``).
+  Chosen when segments are tiny (e.g. leaf -> next level), where
+  block-per-segment padding would explode.
 
 Gathers stay in XLA on purpose: TPU-native big fast gathers feed the
 kernels, matching the hand-written MTTKRP kernel this module retires as
@@ -452,8 +453,8 @@ class PallasPlanExecutor(VectorizedExecutor):
                 arr = out2d.reshape((nseg,) + oshape)
                 return arr.reshape(oshape) if out_lvl == 0 else arr
 
-        # product stage: fused per-fiber contraction; sparse reduction (if
-        # any) stays an XLA segmented scan over sorted CSF segment ids
+        # product stage: fused per-fiber contraction; the sparse reduction
+        # (if any) is the engines' shared sorted segment sum
         P = round_up(nfib, self.block)
         padded = []
         with self._scope("lift"):
@@ -474,12 +475,5 @@ class PallasPlanExecutor(VectorizedExecutor):
             per_fiber = self.lowering.product(ir, padded, dtype)
             arr = per_fiber[:nfib].reshape((nfib,) + oshape)
         if reduce_:
-            with self._scope("reduce"):
-                seg = csf.seg[(lvl, out_lvl)] if out_lvl > 0 else \
-                    jnp.zeros(nfib, jnp.int32)
-                nseg = csf.nfib[out_lvl] if out_lvl > 0 else 1
-                arr = jax.ops.segment_sum(arr, seg, num_segments=nseg,
-                                          indices_are_sorted=True)
-                if out_lvl == 0:
-                    arr = arr[0]
+            arr = self._segment_sum(csf, arr, lvl, out_lvl)
         return arr

@@ -15,6 +15,7 @@ from repro.core import spec as S
 from repro.core.executor import (VectorizedExecutor, make_executor,
                                  prepare_operand)
 from repro.core.planner import plan
+from repro.kernels import segment_sum
 from repro.sparse import build_csf, random_sparse
 
 KERNELS = {
@@ -31,7 +32,7 @@ ENGINES = {
 SCOPED = re.compile(r'op_name="[^"]*(/t\d+\.[a-z.]+/|/out/)')
 
 
-def lowered(kernel: str, engine: str):
+def lowered(kernel: str, engine: str, platform: str | None = None):
     spec = KERNELS[kernel]()
     shape = tuple(spec.dims[i] for i in spec.sparse_indices)
     csf = build_csf(random_sparse(shape, 0.05, seed=0))
@@ -42,7 +43,10 @@ def lowered(kernel: str, engine: str):
         [spec.dims[i] for i in t.indices]).astype(np.float32)
         for t in spec.inputs if not t.is_sparse}
     operand = prepare_operand(ex, csf, factors)
-    return jax.jit(ex.__call__).lower(operand, factors)
+    if platform is None:
+        return jax.jit(ex.__call__).lower(operand, factors)
+    return jax.jit(ex.__call__).trace(operand, factors).lower(
+        lowering_platforms=(platform,))
 
 
 @pytest.mark.parametrize("engine", sorted(ENGINES))
@@ -60,6 +64,25 @@ def test_every_reduction_carries_a_term_scope(kernel, engine):
         assert {"lift", "contract", "reduce"} <= kinds
     else:
         assert any(k.startswith("stage.") for k in kinds), kinds
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_the_segsum_kernel_carries_its_reduce_scope(kernel, monkeypatch):
+    """Lowered for TPU, the XLA engine's sorted segment sums are Mosaic
+    kernels named ``spttn_segsum`` under their term's ``t<i>.reduce``,
+    and no scatter is left under a reduce scope."""
+    monkeypatch.setattr(segment_sum, "default_interpret", lambda: False)
+    text = lowered(kernel, "xla", platform="tpu").as_text(
+        dialect="hlo", debug_info=True)
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert kernels
+    for line in kernels:
+        assert re.search(r'op_name="[^"]*/t\d+\.reduce/spttn_segsum/',
+                         line), line[:300]
+    assert not [line for line in text.splitlines()
+                if " scatter(" in line
+                and re.search(r"/t\d+\.reduce/", line)]
 
 
 @pytest.mark.parametrize("engine", ["xla", "pallas-fused"])

@@ -18,11 +18,13 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import spec as S
 from repro.core.executor import make_executor, prepare_operand
 from repro.core.planner import plan
+from repro.kernels import segment_sum
 from repro.sparse import build_csf, random_sparse
 
 #: kernel -> (spec, sparse dims); widths are the ones users run (R=16 and
@@ -108,3 +110,41 @@ def test_pallas_stages_carry_their_name_and_term_scope(strategy, kind,
     for line in kernels:
         assert re.search(rf'op_name="[^"]*/t\d+\.stage\.{kind}/', line)
         assert f"spttn_{kind}" in line
+
+
+@pytest.mark.parametrize("kernel", ["mttkrp-r16", "mttkrp-r64"])
+def test_xla_engine_reduces_with_the_segsum_kernel(kernel, one_chip,
+                                                    monkeypatch):
+    """On a TPU the XLA engine's sorted segment sums are the one-hot
+    kernel (``spttn_segsum``, under ``t<i>.reduce``), not a scatter."""
+    monkeypatch.setattr(segment_sum, "default_interpret", lambda: False)
+    _, compiled = _compile(kernel, one_chip, "xla")
+    lines = compiled.as_text().splitlines()
+    kernels = [line for line in lines
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(kernels) == 2          # the leaf and the level-1 reduce
+    for line in kernels:
+        assert "spttn_segsum" in line
+        assert re.search(r'op_name="[^"]*/t\d+\.reduce/', line)
+    assert not [line for line in lines if " scatter(" in line
+                and re.search(r"/t0\.reduce/", line)]
+
+
+@pytest.mark.parametrize("w", [16, 64])
+def test_segsum_kernel_compiles_at_the_cell_size(w, one_chip):
+    """The kernel alone at the benchmark cell's leaf reduction, 9,609,928
+    rows into 5,488,373 fibers: its merge path fits SMEM, its blocks
+    VMEM.  Shapes only; the merge path takes its longest length."""
+    n, nseg = 9_609_928, 5_488_373
+    steps = -(-n // segment_sum.IN_BLOCK) + -(-nseg // segment_sum.OUT_BLOCK)
+
+    def reduce_(x, seg, work):
+        return segment_sum.sorted_segment_sum(x, seg, nseg, work,
+                                              interpret=False)
+
+    args = (jax.ShapeDtypeStruct((n, w), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((2 * steps,), jnp.int32,
+                                 sharding=one_chip))
+    text = jax.jit(reduce_).lower(*args).compile().as_text()
+    assert "spttn_segsum" in text and "tpu_custom_call" in text
