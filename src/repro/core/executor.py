@@ -491,7 +491,7 @@ class VectorizedExecutor:
         self.spos = {s: i for i, s in enumerate(spec.sparse_indices)}
         from repro.core.loopnest import fused_sparse_depth
         self.fuse_depth = fused_sparse_depth(path, order, spec.sparse_indices)
-        self._tid = 0      # term being lowered (named scopes, _scope)
+        self._tid, self._fiber_counted = 0, set()  # _scope, fiber bytes
         key_compile_cache_by_metadata()
         self._letter = {}
         for i in spec.all_indices:
@@ -722,20 +722,20 @@ class VectorizedExecutor:
             arr = self._einsum(fa, da, fb, db, out_dense, fiber=True)
         if out_lvl < lvl:
             arr = self._segment_sum(csf, arr, lvl, out_lvl)
+        self._count_fiber_bytes(csf, lvl, out_dense, arr.dtype)
         return arr
 
     def _segment_sum(self, csf: CSFArrays, arr: jnp.ndarray, lvl: int,
                      out_lvl: int) -> jnp.ndarray:
-        """Sum level-``lvl`` fiber rows into their level-``out_lvl``
-        parents (``out_lvl == 0``: into one row, returned without its
-        fiber axis), under the term's ``reduce`` scope.
+        """Sum level-``lvl`` fiber rows into their level-``out_lvl`` parents
+        (``out_lvl == 0``: into one row, returned without its fiber axis),
+        under the term's ``reduce`` scope.  CSF order is lexicographic, so
+        the segment ids are sorted.
 
-        CSF order is lexicographic, so the segment ids are sorted.  On
-        TPU a float32 reduction whose operand carries its merge path
-        (:func:`repro.kernels.segment_sum.attached_work`) runs the
-        one-hot kernel and counts ``reduce.kernel``; any other runs XLA's
-        sorted ``segment_sum`` and counts ``reduce.scatter``.
-        """
+        On TPU a float32 reduction whose operand carries its merge path
+        (:func:`repro.kernels.segment_sum.attached_work`) runs the one-hot
+        kernel and counts ``reduce.kernel``; any other runs XLA's sorted
+        ``segment_sum`` and counts ``reduce.scatter``."""
         from repro.kernels import segment_sum as ss
         with self._scope("reduce"):
             work = ss.attached_work(csf, lvl, out_lvl, arr.dtype)
@@ -750,6 +750,26 @@ class VectorizedExecutor:
             arr = jax.ops.segment_sum(arr, seg, num_segments=nseg,
                                       indices_are_sorted=True)
             return arr[0] if out_lvl == 0 else arr
+
+    def _count_fiber_bytes(self, csf: CSFArrays, lvl: int,
+                           dense: tuple[str, ...], dtype) -> None:
+        """Add the bytes of a contraction's level-``lvl`` fiber array (a
+        row per fiber, ``dense`` wide) to the counter
+        ``contract.fiber_bytes``, at trace time.
+
+        Counted once per engine, term and fiber count: an engine traced
+        twice at one shape (``prepare_operand``'s abstract trace, then
+        ``jax.jit``'s) writes the arrays once a call, so the counter holds
+        the bytes one call of each engine writes.  Called after the
+        reduction, and kept below every traced line of this module, so
+        that no op's source line (op metadata, part of the compile-cache
+        key: :func:`key_compile_cache_by_metadata`) moved when it came."""
+        key = (self._tid, lvl, csf.nfib[lvl], jnp.dtype(dtype))
+        if key not in self._fiber_counted:
+            self._fiber_counted.add(key)
+            width = int(np.prod([self.spec.dims[i] for i in dense]))
+            count("contract.fiber_bytes",
+                  csf.nfib[lvl] * width * jnp.dtype(dtype).itemsize)
 
 
 def execute_unfactorized(spec: SpTTNSpec, csf: CSFArrays,

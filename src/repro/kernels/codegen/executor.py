@@ -476,4 +476,5 @@ class PallasPlanExecutor(VectorizedExecutor):
             arr = per_fiber[:nfib].reshape((nfib,) + oshape)
         if reduce_:
             arr = self._segment_sum(csf, arr, lvl, out_lvl)
+        self._count_fiber_bytes(csf, lvl, out_dense, dtype)
         return arr

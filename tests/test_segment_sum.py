@@ -14,10 +14,12 @@ import pytest
 from repro import spans
 from repro.core import spec as S
 from repro.core.executor import (CSFArrays, layout_bytes, make_executor,
-                                 prepare_operand, reference_execute)
+                                 plan_executor, prepare_operand,
+                                 reference_execute)
 from repro.core.planner import plan
 from repro.kernels import segment_sum as ss
 from repro.sparse import build_csf, random_sparse
+from repro.sparse.coo import COOTensor
 
 #: small blocks, so small maps span many of them
 TO, TI = 128, 256
@@ -227,3 +229,43 @@ def test_off_tpu_the_engines_keep_segment_sum():
     assert kernels == 0 and scatters >= 1
     assert "scatter" in text and "pallas" not in text
     assert not operand.__dict__.get("_codegen_layouts")
+
+
+def _power_law_csf(shape, nnz, seed=0, alpha=0.8):
+    """Every mode's index drawn with weight ``(i + 1) ** -alpha`` (the
+    benchmark's pattern, at a small size): skewed slices and fibers on
+    every level, not only the first."""
+    rng = np.random.default_rng(seed)
+    cols = []
+    for n in shape:
+        w = np.arange(1, n + 1, dtype=np.float64) ** -alpha
+        cols.append(rng.choice(n, size=2 * nnz, p=w / w.sum()))
+    keys = np.unique(np.ravel_multi_index(tuple(cols), shape))
+    keys = np.sort(rng.choice(keys, size=nnz, replace=False))
+    coords = np.stack(np.unravel_index(keys, shape), axis=1)
+    return build_csf(COOTensor(
+        coords=coords.astype(np.int32),
+        values=rng.standard_normal(len(keys)).astype(np.float32),
+        shape=shape))
+
+
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_rank_8_ttmc3_with_the_kernel_matches_the_reference(engine,
+                                                            kernel_on):
+    """The HOOI cell's kernel, a rank-(8, 8) TTMc3 (a 64-wide level-2
+    intermediate reduced by the kernel, after an 8-wide leaf one), through
+    ``plan`` -> ``plan_executor`` on a power-law pattern."""
+    spec = S.ttmc3(40, 30, 50, 8, 8)
+    csf = _power_law_csf((40, 30, 50), 1500)
+    p = plan(spec, nnz_levels=csf.nnz_levels())
+    ex = plan_executor(p, **ENGINES[engine])
+    rng = np.random.default_rng(1)
+    factors = {"U": rng.standard_normal((30, 8)).astype(np.float32),
+               "V": rng.standard_normal((50, 8)).astype(np.float32)}
+    operand = prepare_operand(ex, csf, factors)
+    spans.reset()
+    out = jax.jit(ex.__call__)(operand, factors)
+    kernels, scatters = _counts()
+    assert kernels == 2 and scatters == 0
+    ref = reference_execute(spec, p.path, p.order, csf, factors)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)

@@ -12,7 +12,9 @@ from repro import spans
 from repro.autotune.cache import PlanCache
 from repro.autotune.tuner import SearchStats, TunerConfig, tune
 from repro.core import spec as S
-from repro.core.executor import CSFArrays
+from repro.core.executor import (CSFArrays, VectorizedExecutor,
+                                 plan_executor, prepare_operand)
+from repro.core.planner import plan
 from repro.sparse import build_csf, build_csf_batch, random_sparse
 
 
@@ -157,3 +159,65 @@ def test_pallas_candidates_record_their_layout_peak():
     _, stats = tune(spec, csf=csf, tuner=cfg)
     assert stats.layout_bytes_max > 0
     assert np.isfinite(stats.best_seconds)
+
+
+#: kernel -> (spec, (leaf width, level-2 width)) of the default plan: the
+#: leaf term writes ``nnz x R2``, the level-2 term ``nnz^(IJ) x R1 (R2)``
+FIBER_KERNELS = {
+    "mttkrp-r16": (lambda: S.mttkrp(30, 20, 25, 16), (16, 16)),
+    "ttmc-r8": (lambda: S.ttmc3(30, 20, 25, 8, 8), (8, 64)),
+}
+FIBER_ENGINES = {"xla": dict(backend="xla"),
+                 "pallas-segsum": dict(backend="pallas", strategy="segsum",
+                                       block=8)}
+
+
+def _fiber_problem(kernel, engine):
+    spec = FIBER_KERNELS[kernel][0]()
+    csf = build_csf(random_sparse((30, 20, 25), 0.05, seed=0,
+                                  distribution="frostt"))
+    p = plan(spec, nnz_levels=csf.nnz_levels())
+    ex = plan_executor(p, **FIBER_ENGINES[engine])
+    rng = np.random.default_rng(0)
+    factors = {t.name: rng.standard_normal(
+        [spec.dims[i] for i in t.indices]).astype(np.float32)
+        for t in spec.inputs if not t.is_sparse}
+    return csf, ex, factors
+
+
+@pytest.mark.parametrize("engine", sorted(FIBER_ENGINES))
+@pytest.mark.parametrize("kernel", sorted(FIBER_KERNELS))
+def test_fiber_bytes_are_the_level_count_reckoning(kernel, engine):
+    """``contract.fiber_bytes`` holds the float32 bytes of the fiber
+    arrays one call writes, from the level counts alone; the abstract
+    trace of ``prepare_operand`` and the jit's trace count them once."""
+    csf, ex, factors = _fiber_problem(kernel, engine)
+    operand = prepare_operand(ex, csf, factors)
+    jax.jit(ex.__call__)(operand, factors)
+    jax.jit(ex.__call__).lower(operand, factors)
+    leaf, level2 = FIBER_KERNELS[kernel][1]
+    n = csf.nnz_levels()
+    assert spans.counters()["contract.fiber_bytes"] == 4 * (
+        n[3] * leaf + n[2] * level2)
+
+
+@pytest.mark.parametrize("kernel", sorted(FIBER_KERNELS))
+def test_counting_fiber_bytes_leaves_the_program_unchanged(kernel,
+                                                           monkeypatch):
+    """The count is host work at trace time: the program, with its op
+    metadata (scopes, source lines), is the same without it."""
+    def text():
+        csf, ex, factors = _fiber_problem(kernel, "xla")
+        operand = prepare_operand(ex, csf, factors)
+        return jax.jit(ex.__call__).lower(operand, factors).as_text(
+            debug_info=True)
+
+    texts = []
+    for count in (True, False):     # one call site: its line is metadata
+        if not count:
+            monkeypatch.setattr(VectorizedExecutor, "_count_fiber_bytes",
+                                lambda *a: None)
+        texts.append(text())
+        assert bool(spans.counters().get("contract.fiber_bytes")) == count
+        spans.reset()
+    assert texts[0] == texts[1]

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import spec as S
-from repro.core.executor import make_executor, prepare_operand
+from repro.core.executor import CSFArrays, make_executor, prepare_operand
 from repro.core.planner import plan
 from repro.kernels import segment_sum
 from repro.sparse import build_csf, random_sparse
@@ -148,3 +148,47 @@ def test_segsum_kernel_compiles_at_the_cell_size(w, one_chip):
                                  sharding=one_chip))
     text = jax.jit(reduce_).lower(*args).compile().as_text()
     assert "spttn_segsum" in text and "tpu_custom_call" in text
+
+
+def test_hooi_cell_ttmc3_fits_the_chip(one_chip, monkeypatch):
+    """The XLA engine's rank-(8, 8) TTMc3 at the HOOI cell's level counts
+    (mode 0 of the 9,609,928-nnz pattern), on the planner's leaf-reduce
+    plan: its temporaries, 5.23 GB when this test was written, stay under
+    6.5 GB of the chip's 16, and both reductions are the kernel, one 8
+    wide at the leaf and one 64 wide at level 2.  A sorted synthetic
+    pattern with those counts stands for the cell's: only the shapes and
+    the merge paths' lengths reach the compiler."""
+    monkeypatch.setattr(segment_sum, "default_interpret", lambda: False)
+    dims = (12_092, 9_184, 28_818)
+    nfib = {1: 12_092, 2: 5_488_373, 3: 9_609_928}
+
+    def parents(child, par):
+        if par == 0:
+            return np.zeros(nfib[child], np.int32)
+        n = np.arange(nfib[child], dtype=np.int64)
+        return (n * nfib[par] // nfib[child]).astype(np.int32)
+
+    arrays = CSFArrays(
+        values=np.zeros(nfib[3], np.float32),
+        fiber_coord={p: {m: np.zeros(nfib[p], np.int32) for m in range(p)}
+                     for p in nfib},
+        seg={(c, p): parents(c, p) for c in nfib for p in range(c)},
+        nfib=nfib, order=3, shape=dims)
+    spec = S.ttmc3(*dims, 8, 8)
+    p = plan(spec, nnz_levels={0: 1, **nfib})
+    ex = make_executor(spec, p.path, p.order, backend="xla")
+    factors = {"U": jax.ShapeDtypeStruct((dims[1], 8), jnp.float32),
+               "V": jax.ShapeDtypeStruct((dims[2], 8), jnp.float32)}
+    operand = prepare_operand(ex, arrays, factors)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (operand, factors))
+    compiled = jax.jit(ex.__call__).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6.5e9
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert all("spttn_segsum" in line for line in kernels)
+    widths = sorted(int(re.search(r"= f32\[(\d+),", line).group(1))
+                    for line in kernels)
+    assert widths == [8, 64]
